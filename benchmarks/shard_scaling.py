@@ -11,7 +11,10 @@ before jax initialises, and every *other* benchmark must keep seeing one
 device (their tracked numbers would silently change run conditions
 otherwise), so :func:`run` re-executes this module as a **subprocess
 child** with the flag set and collects its rows from stdout JSON — same
-pattern as the multi-device tests (``tests/test_pipeline.py``).
+pattern as the multi-device tests (``tests/test_pipeline.py``).  On an
+accelerator the parent already holds the device and the child cannot get
+it, so both refuse to run off the CPU; ``benchmarks.run`` keeps this module
+out of its default sweep (``--only shard_scaling`` runs it).
 
 Reading the rows: host-platform "devices" are slices of ONE CPU whose
 single-device XLA already uses every core, so adding fake devices only adds
@@ -41,6 +44,8 @@ def _child() -> list[dict]:
 
     from benchmarks.common import time_fn
     from repro.core import engine as _engine
+
+    _require_cpu()
     from repro.core.swag import num_windows
     from repro.obs.export import to_jsonable
     from repro.query import Query, Window, execute, plan
@@ -125,7 +130,18 @@ def _child() -> list[dict]:
     return rows
 
 
+def _require_cpu() -> None:
+    import jax
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "shard_scaling measures virtual CPU devices in a child process; "
+            f"on {jax.default_backend()!r} this process already holds the "
+            "device, so the child could not get it — run the sharded "
+            "phases of chip_smoke.py --four-chips instead")
+
+
 def run() -> list[dict]:
+    _require_cpu()
     env = dict(os.environ)
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                         + env.get("XLA_FLAGS", ""))

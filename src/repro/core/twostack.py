@@ -45,7 +45,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.combiners import get_combiner
-from repro.kernels.common import _shift_left, _shift_right
 
 Array = jax.Array
 
@@ -90,13 +89,27 @@ def _region(keys: Array, lo: Array, length: Array, wcap: int):
     return keys[idx], live
 
 
+def _shift_right(x: Array, d: int, fill) -> Array:
+    """x[i] <- x[i-d] along the last axis (static d), front-filled."""
+    pad = jnp.full(x.shape[:-1] + (d,), fill, x.dtype)
+    return jnp.concatenate([pad, x[..., :-d]], axis=-1)
+
+
+def _shift_left(x: Array, d: int, fill) -> Array:
+    """x[i] <- x[i+d] along the last axis (static d), back-filled."""
+    pad = jnp.full(x.shape[:-1] + (d,), fill, x.dtype)
+    return jnp.concatenate([x[..., d:], pad], axis=-1)
+
+
 def flip_scans(kf: Array, vf: Array, kb: Array, vb: Array, names,
-               key_dtype) -> dict:
+               key_dtype, *, shift_left=_shift_left,
+               shift_right=_shift_right) -> dict:
     """The batched flip: per op, an inclusive *suffix* scan over the front
     slices and an inclusive *prefix* scan over the back slices (masked
     lanes pinned to the op's identity).  Pure ``jnp`` over the last axis —
     the same code runs batched ``[NE, wcap]`` on the reference backend and
-    per-row inside the Pallas kernel.  Returns
+    per-row inside the Pallas kernel, which passes its own lane shifts
+    (``shift_left``/``shift_right``, ``(x, d, fill)``).  Returns
     ``{name: (front_suffix, back_prefix)}``."""
     wcap = kf.shape[-1]
     out = {}
@@ -110,9 +123,9 @@ def flip_scans(kf: Array, vf: Array, kb: Array, vb: Array, names,
         d = 1
         while d < wcap:
             f = comb.op(f, jax.tree.map(
-                lambda s, i: _shift_left(s, d, i), f, ident))
+                lambda s, i: shift_left(s, d, i), f, ident))
             b = comb.op(jax.tree.map(
-                lambda s, i: _shift_right(s, d, i), b, ident), b)
+                lambda s, i: shift_right(s, d, i), b, ident), b)
             d *= 2
         out[name] = (f, b)
     return out

@@ -17,7 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.distributed import _compat
 
 
 def compress(x, err):
@@ -74,8 +73,7 @@ def make_pod_sync(mesh, grad_specs):
     out_specs = in_specs
 
     def pod_sync(grads, err):
-        return _compat.shard_map(_tree_sync, mesh=mesh,
-                                 in_specs=in_specs, out_specs=out_specs,
-                                 check=False)(grads, err)
+        return jax.shard_map(_tree_sync, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(grads, err)
 
     return pod_sync

@@ -15,7 +15,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.distributed import _compat
 from jax.sharding import PartitionSpec as P
 
 
@@ -80,9 +79,9 @@ def pipeline_apply(fn_stage, params_stages, x_mb, *, mesh,
     stage_spec = jax.tree.map(
         lambda _: P(pod_axis), params_stages,
         is_leaf=lambda x: hasattr(x, "shape"))
-    return _compat.shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(stage_spec, rep),
         out_specs=rep,
-        check=False,
+        check_vma=False,
     )(params_stages, x_mb)

@@ -54,7 +54,6 @@ from repro.core import engine as _engine
 from repro.core import sorter
 from repro.core import streaming as _streaming
 from repro.core.combiners import Combiner
-from repro.distributed import _compat
 
 # the package attribute ``repro.core.swag`` is shadowed by the deprecated
 # ``swag`` entry-point function, so resolve the *module* explicitly
@@ -101,8 +100,8 @@ def _map_shards(fn, mesh, args):
     def body(*a):
         return jax.vmap(fn)(*a)
 
-    return _compat.shard_map(body, mesh=mesh, in_specs=spec,
-                             out_specs=spec)(*args)
+    return jax.shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)(*args)
 
 
 def combine_tree(tables: _engine.PartialTable, ops, *, key_dtype,

@@ -4,9 +4,9 @@ The paper feeds its engine from an FPGA merge sorter.  On TPU the analogue
 for window/tile-scale sorts (the paper's SWAG windows are <= 4K tuples, which
 fit VMEM) is a bitonic network executed entirely on-chip:
 
-  * the ``p ^ j`` partner pairing is rendered as a reshape to
-    ``[T/(2j), 2, j]`` so partners sit on an adjacent axis — every
-    compare-exchange is a vectorized select, **no gathers**;
+  * the ``p ^ j`` partner is one of two lane rolls, picked by bit ``j`` of
+    the lane index — every compare-exchange is two rolls and a select,
+    **no gathers**;
   * log2(T)*(log2(T)+1)/2 sweeps, each O(T) vector work, fixed at trace time
     (the FPGA's fixed wiring becomes a fixed unrolled schedule);
   * multi-operand: sorts (group, key) lexicographically and drags any number
@@ -27,10 +27,10 @@ from repro.kernels import common
 def _kernel(*refs, n_ops: int, num_keys: int):
     in_refs = refs[:n_ops]
     out_refs = refs[n_ops:]
-    operands = tuple(r[0, :] for r in in_refs)
+    operands = tuple(r[...] for r in in_refs)
     out = common.bitonic_sort_tile(operands, num_keys=num_keys)
     for r, o in zip(out_refs, out):
-        r[0, :] = o
+        r[...] = o
 
 
 def bitonic_pallas(operands: tuple, num_keys: int, *, interpret: bool) -> tuple:

@@ -35,29 +35,27 @@ from repro.core.engine import PAD_GROUP
 from repro.kernels import common
 
 
-def _kernel(g_ref, k_ref, og_ref, ov_ref, oc_ref,
-            pg_ref, pv_ref, *pstate_refs, combiner: Combiner):
+def _kernel(g_ref, k_ref, og_ref, ov_ref, pg_ref, pv_ref, *pstate_refs,
+            combiner: Combiner):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
-        pg_ref[0, 0] = jnp.full((), PAD_GROUP, jnp.int32)
-        pv_ref[0, 0] = jnp.zeros((), jnp.int32)
+        pg_ref[...] = jnp.full((1, 1), PAD_GROUP, jnp.int32)
+        pv_ref[...] = jnp.zeros((1, 1), jnp.int32)
         for r in pstate_refs:
-            r[0, 0] = jnp.zeros((), r.dtype)
+            r[...] = jnp.zeros((1, 1), r.dtype)
 
-    g = g_ref[0, :]
-    k = k_ref[0, :]
+    g = g_ref[...]
+    k = k_ref[...]
     t = g.shape[-1]
 
     # ---- (b) entities t: run boundaries from shifted compares ----
     sentinel = jnp.iinfo(jnp.int32).min  # no valid group id (contract: > INT32_MIN)
-    g_prev = common._shift_right(g, 1, sentinel)    # lane 0 forced start
-    starts = g != g_prev
-    g_next = common._shift_left(g, 1, sentinel)
-    ends = g != g_next
-    lane = jax.lax.broadcasted_iota(jnp.int32, g.shape, 0)
-    ends = ends & (lane != t - 1)                   # trailing run is withheld
+    starts = g != common.shift_right(g, 1, sentinel)   # lane 0 forced start
+    ends = g != common.shift_left(g, 1, sentinel)
+    lane = common.lane_iota(g)
+    ends = ends & (lane != t - 1)                      # trailing run is withheld
 
     # ---- (c) entities n: in-tile rolling segmented prefix scan ----
     state = combiner.lift(k)
@@ -65,13 +63,12 @@ def _kernel(g_ref, k_ref, og_ref, ov_ref, oc_ref,
     scanned = common.tile_segmented_scan(starts, state, combiner)
 
     # merge the carried (pending) run if it continues into this tile
-    pending_g = pg_ref[0, 0]
-    pending_valid = pv_ref[0, 0] != 0
-    pending_state = jax.tree.unflatten(
-        treedef, [r[0, 0][None] for r in pstate_refs])
-    first_run = jnp.cumsum(starts.astype(jnp.int32)) == 1
-    continues = pending_valid & (pending_g == g[0])
-    merge_mask = first_run & continues
+    pending_g = pg_ref[...]
+    pending_valid = pv_ref[...] != 0
+    pending_state = jax.tree.unflatten(treedef, [r[...] for r in pstate_refs])
+    first_run = common.prefix_sum(starts.astype(jnp.int32)) == 1
+    g0 = common.lane_at(g, 0)
+    merge_mask = first_run & pending_valid & (pending_g == g0)
     merged_all = combiner.op(pending_state, scanned)
     merged = jax.tree.map(
         lambda m, s: jnp.where(merge_mask, m, s), merged_all, scanned)
@@ -81,34 +78,32 @@ def _kernel(g_ref, k_ref, og_ref, ov_ref, oc_ref,
     emit = ends & (g != PAD_GROUP)
 
     # ---- (e) reverse butterfly: dense round-robin compaction ----
-    (cg, cv), cnt = common.butterfly_compact(
+    (cg, cv), _ = common.butterfly_compact(
         emit, (g, values), (PAD_GROUP, jnp.zeros((), values.dtype)))
 
     # emit the pending run if this tile does not continue it
-    emit_pending = pending_valid & (pending_g != g[0]) & (pending_g != PAD_GROUP)
-    pend_val = combiner.finalize(
-        jax.tree.unflatten(treedef, [r[0, 0][None] for r in pstate_refs]))[0]
+    emit_pending = pending_valid & (pending_g != g0) & (pending_g != PAD_GROUP)
+    pend_val = combiner.finalize(pending_state)
     lane0 = lane == 0
-    cg_shift = jnp.where(lane0, pending_g, common._shift_right(cg, 1, PAD_GROUP))
-    cv_shift = jnp.where(lane0, pend_val, common._shift_right(cv, 1, 0))
-    out_g = jnp.where(emit_pending, cg_shift, cg)
-    out_v = jnp.where(emit_pending, cv_shift, cv)
-
-    og_ref[0, :] = out_g
-    ov_ref[0, :] = out_v
-    oc_ref[0, 0] = cnt[0] + emit_pending.astype(jnp.int32)
+    cg_shift = jnp.where(lane0, pending_g, common.shift_right(cg, 1, PAD_GROUP))
+    cv_shift = jnp.where(lane0, pend_val, common.shift_right(cv, 1, 0))
+    og_ref[0] = jnp.where(emit_pending, cg_shift, cg)
+    ov_ref[0] = jnp.where(emit_pending, cv_shift, cv)
 
     # ---- new pending = this tile's trailing run ----
-    tail_state = jax.tree.map(lambda x: x[-1], merged)
-    pg_ref[0, 0] = g[-1]
-    pv_ref[0, 0] = (g[-1] != PAD_GROUP).astype(jnp.int32)
-    for r, leaf in zip(pstate_refs, jax.tree.leaves(tail_state)):
-        r[0, 0] = leaf
+    last_g = common.lane_at(g, t - 1)
+    pg_ref[...] = last_g
+    pv_ref[...] = (last_g != PAD_GROUP).astype(jnp.int32)
+    for r, leaf in zip(pstate_refs, jax.tree.leaves(merged)):
+        r[...] = common.lane_at(leaf, t - 1)
 
 
 def groupagg_pallas(groups, keys, combiner: Combiner, *, tile: int,
                     out_dtype, interpret: bool):
-    """groups/keys: [1, N] with N % tile == 0, PAD_GROUP-closed."""
+    """groups/keys: [1, N] with N % tile == 0, PAD_GROUP-closed.  Returns
+    per-tile compacted ``(og, ov [tiles, tile], oc [tiles])``; ``og`` is
+    PAD_GROUP past each tile's emitted groups, which is how ``oc``
+    counts them."""
     n = groups.shape[-1]
     num_tiles = n // tile
     probe = combiner.lift(jnp.zeros((1,), keys.dtype))
@@ -116,21 +111,20 @@ def groupagg_pallas(groups, keys, combiner: Combiner, *, tile: int,
 
     kern = functools.partial(_kernel, combiner=combiner)
     block = pl.BlockSpec((1, tile), lambda i: (0, i))
-    out_block = pl.BlockSpec((1, tile), lambda i: (i, 0))
-    cnt_block = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    og, ov, oc = pl.pallas_call(
+    out_block = pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0))
+    og, ov = pl.pallas_call(
         kern,
         grid=(num_tiles,),
         in_specs=[block, block],
-        out_specs=[out_block, out_block, cnt_block],
+        out_specs=[out_block, out_block],
         out_shape=[
-            jax.ShapeDtypeStruct((num_tiles, tile), jnp.int32),
-            jax.ShapeDtypeStruct((num_tiles, tile), out_dtype),
-            jax.ShapeDtypeStruct((num_tiles, 1), jnp.int32),
+            jax.ShapeDtypeStruct((num_tiles, 1, tile), jnp.int32),
+            jax.ShapeDtypeStruct((num_tiles, 1, tile), out_dtype),
         ],
         scratch_shapes=(
             [pltpu.VMEM((1, 1), jnp.int32), pltpu.VMEM((1, 1), jnp.int32)]
             + [pltpu.VMEM((1, 1), d) for d in leaf_dtypes]),
         interpret=interpret,
     )(groups, keys)
-    return og, ov, oc[:, 0]
+    og, ov = og[:, 0, :], ov[:, 0, :]
+    return og, ov, jnp.sum((og != PAD_GROUP).astype(jnp.int32), axis=-1)

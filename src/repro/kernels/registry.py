@@ -225,7 +225,9 @@ def choose_backend(query, devices=None, num_shards: int = 1) -> str:
     but only when **two or more** candidates have measured cells.  A
     single cell proves nothing about the alternatives (and on CPU it
     would usually be the reference path's own telemetry re-electing
-    itself), so anything less falls back to the static choice.
+    itself), so anything less falls back to the static choice.  On an
+    accelerator the measured choice is made among the kernel backends
+    only: a query a kernel can run never lands on ``reference`` there.
 
     The static probe: on CPU every kernel would run in Pallas interpret
     mode — a correctness tool, orders of magnitude slower than the
@@ -240,24 +242,31 @@ def choose_backend(query, devices=None, num_shards: int = 1) -> str:
     ``reference`` | ``pallas`` | ``pallas-panes`` locally, with its
     per-shard kernels unchanged.
     """
+    return choose_backend_why(query, devices, num_shards)[0]
+
+
+def choose_backend_why(query, devices=None, num_shards: int = 1
+                       ) -> tuple[str, str]:
+    """:func:`choose_backend` plus how the choice was made (``"measured"``,
+    ``"cpu"`` or ``"accelerator"``) — the planner records it on the
+    plan's note."""
     candidates = [name for name in ("pallas-panestore", "pallas-panes",
                                     "pallas", "reference")
                   if get_backend(name).supports(query) is None]
+    on_cpu = common.is_cpu(devices)
+    kernels = [name for name in candidates if name != "reference"]
+    among = candidates if on_cpu or not kernels else kernels
 
     # measured-cost routing (lazy import: repro.obs must stay importable
     # without the kernels package and vice versa)
     from repro.obs.registry import METRICS, query_fingerprint
     fp = query_fingerprint(query, num_shards=num_shards)
-    measured = [name for name in candidates
-                if METRICS.tuples_per_s(name, fp)]
+    measured = [name for name in among if METRICS.tuples_per_s(name, fp)]
     if len(measured) >= 2:
-        best = METRICS.best_backend(fp, among=candidates)
+        best = METRICS.best_backend(fp, among=among)
         if best is not None:
-            return best
+            return best, "measured"
 
-    if common.is_cpu(devices):
-        return "reference"
-    for name in candidates:
-        if name != "reference":
-            return name
-    return "reference"
+    if on_cpu:
+        return "reference", "cpu"
+    return (kernels or ["reference"])[0], "accelerator"

@@ -37,34 +37,35 @@ def _kernel(flags_ref, *refs, combiner: Combiner, n_leaves: int):
 
     @pl.when(i == 0)
     def _init():
-        cflag_ref[0, 0] = jnp.zeros((), jnp.int32)
+        cflag_ref[...] = jnp.zeros((1, 1), jnp.int32)
         for r in carry_refs:
-            r[0, 0] = jnp.zeros((), r.dtype)
+            r[...] = jnp.zeros((1, 1), r.dtype)
 
-    flags = flags_ref[0, :] != 0
-    leaves = tuple(r[0, :] for r in in_refs)
+    flags = flags_ref[...]
+    leaves = tuple(r[...] for r in in_refs)
     treedef = combiner_treedef(combiner, leaves)
     state = jax.tree.unflatten(treedef, list(leaves))
+    t = flags.shape[-1]
 
     # force a tile-local segment start at lane 0; the true continuation is
     # re-attached through the carry below
-    lane0 = jax.lax.broadcasted_iota(jnp.int32, flags.shape, 0) == 0
-    local_flags = flags | lane0
-    scanned = common.tile_segmented_scan(local_flags, state, combiner)
+    lane0 = common.lane_iota(flags) == 0
+    scanned = common.tile_segmented_scan((flags != 0) | lane0, state,
+                                         combiner)
 
     # lanes still inside the run that crosses the tile boundary
-    open_mask = (jnp.cumsum(flags.astype(jnp.int32)) == 0) & (cflag_ref[0, 0] != 0)
-    carry_state = jax.tree.unflatten(
-        treedef, [r[0, 0][None] for r in carry_refs])
+    open_mask = ((common.prefix_sum((flags != 0).astype(jnp.int32)) == 0)
+                 & (cflag_ref[...] != 0))
+    carry_state = jax.tree.unflatten(treedef, [r[...] for r in carry_refs])
     merged_all = combiner.op(carry_state, scanned)
     merged = jax.tree.map(
         lambda m, s: jnp.where(open_mask, m, s), merged_all, scanned)
 
     for r, leaf in zip(out_refs, jax.tree.leaves(merged)):
-        r[0, :] = leaf
+        r[...] = leaf
     for r, leaf in zip(carry_refs, jax.tree.leaves(merged)):
-        r[0, 0] = leaf[-1]
-    cflag_ref[0, 0] = jnp.ones((), jnp.int32)
+        r[...] = common.lane_at(leaf, t - 1)
+    cflag_ref[...] = jnp.ones((1, 1), jnp.int32)
 
 
 def combiner_treedef(combiner: Combiner, leaves):

@@ -34,6 +34,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import panestore as _panestore
 from repro.core.combiners import get_combiner
@@ -41,29 +42,36 @@ from repro.core.engine import PAD_GROUP
 from repro.kernels import common
 
 
+def _row_block(width: int, off: int = 0) -> pl.BlockSpec:
+    """Block ``(1, 1, width)`` of a ``[rows, 1, width]`` array at row
+    ``i + off``: the row layout Mosaic accepts for one-row tiles (the last
+    two block dims equal the array's)."""
+    return pl.BlockSpec((1, 1, width), lambda i: (i + off, 0, 0))
+
+
+def _rows(x):
+    """``[rows, width]`` -> the ``[rows, 1, width]`` kernel row layout."""
+    return x[:, None, :]
+
+
 def _median_in_tile(g, k):
     """Lower median per group over one closed, (group,key)-sorted window."""
     sentinel = jnp.iinfo(jnp.int32).min
-    starts = g != common._shift_right(g, 1, sentinel)
-    ends = g != common._shift_left(g, 1, sentinel)
+    starts = g != common.shift_right(g, 1, sentinel)
+    ends = g != common.shift_left(g, 1, sentinel)
 
     count = get_combiner("count")
     ranks = common.tile_segmented_scan(starts, count.lift(k), count)  # 1-based
     card_at_end = jnp.where(ends, ranks, 0)
-
     # broadcast cardinality backwards: reversed max-segscan seeded at run ends
-    g_rev = jnp.flip(g, axis=-1)
-    card_rev = jnp.flip(card_at_end, axis=-1)
-    starts_rev = g_rev != common._shift_right(g_rev, 1, sentinel)
-    mx = get_combiner("max")
-    card_bcast = jnp.flip(
-        common.tile_segmented_scan(starts_rev, card_rev, mx), axis=-1)
+    card = common.tile_segmented_scan(ends, card_at_end, get_combiner("max"),
+                                      reverse=True)
 
-    is_med = (ranks - 1) == (card_bcast - 1) // 2
+    is_med = (ranks - 1) == ((card - 1) >> 1)
     emit = is_med & (g != PAD_GROUP)
-    (cg, cv), cnt = common.butterfly_compact(
+    (cg, cv), _ = common.butterfly_compact(
         emit, (g, k), (PAD_GROUP, jnp.zeros((), k.dtype)))
-    return cg, cv, cnt
+    return cg, cv
 
 
 def _multi_tails_in_tile(g, k, combiners: dict):
@@ -74,11 +82,12 @@ def _multi_tails_in_tile(g, k, combiners: dict):
     reverse-butterfly compaction pass (``butterfly_compact`` routes the group
     column and all value columns through the same displacement network —
     the hardware's PRRA serving N ``function_select`` units at once).
-    Returns ``(cg, {name: cv}, cnt)``.
+    Returns ``(cg, {name: cv})``; ``cg`` is PAD_GROUP past the emitted
+    groups, so the wrapper counts them.
     """
     sentinel = jnp.iinfo(jnp.int32).min
-    starts = g != common._shift_right(g, 1, sentinel)
-    ends = g != common._shift_left(g, 1, sentinel)
+    starts = g != common.shift_right(g, 1, sentinel)
+    ends = g != common.shift_left(g, 1, sentinel)
 
     vals, fills, names = [], [], []
     for name, comb in combiners.items():
@@ -91,33 +100,33 @@ def _multi_tails_in_tile(g, k, combiners: dict):
         names.append(name)
 
     out = {}
-    cg = cnt = None
+    cg = None
     if names:
         emit = ends & (g != PAD_GROUP)
-        compacted, cnt = common.butterfly_compact(
+        compacted, _ = common.butterfly_compact(
             emit, (g, *vals), (PAD_GROUP, *fills))
         cg = compacted[0]
         out.update(zip(names, compacted[1:]))
     if None in combiners.values():
-        mg, mv, mcnt = _median_in_tile(g, k)
+        mg, mv = _median_in_tile(g, k)
         med_name = next(n for n, c in combiners.items() if c is None)
         out[med_name] = mv
         if cg is None:
-            cg, cnt = mg, mcnt
-    return cg, out, cnt
+            cg = mg
+    return cg, out
+
+
+def _write_tails(out_refs, cg, vals, combiners):
+    og_ref, *ov_refs = out_refs
+    og_ref[0] = cg
+    for name, ov_ref in zip(combiners, ov_refs):
+        ov_ref[0] = vals[name]
 
 
 def _kernel(g_ref, k_ref, *out_refs, combiners: dict):
-    g = g_ref[0, :]
-    k = k_ref[0, :]
     # (window buffer has already framed WS/WA; sort = the paper's small sorter)
-    g, k = common.bitonic_sort_tile((g, k), num_keys=2)
-    cg, vals, cnt = _multi_tails_in_tile(g, k, combiners)
-    og_ref, *ov_refs, oc_ref = out_refs
-    og_ref[0, :] = cg
-    for name, ov_ref in zip(combiners, ov_refs):
-        ov_ref[0, :] = vals[name]
-    oc_ref[0, 0] = cnt[0]
+    g, k = common.bitonic_sort_tile((g_ref[0], k_ref[0]), num_keys=2)
+    _write_tails(out_refs, *_multi_tails_in_tile(g, k, combiners), combiners)
 
 
 def _out_dtype(op: str, key_dtype):
@@ -129,41 +138,52 @@ def _out_dtype(op: str, key_dtype):
         jax.ShapeDtypeStruct((1,), key_dtype)).dtype
 
 
+def _window_outputs(nw, ws, combiners, key_dtype):
+    return ([jax.ShapeDtypeStruct((nw, 1, ws), jnp.int32)]
+            + [jax.ShapeDtypeStruct((nw, 1, ws), _out_dtype(name, key_dtype))
+               for name in combiners])
+
+
+def _window_results(outs, combiners):
+    """Kernel outputs -> ``(og [NW, WS], {name: ov}, oc [NW])``."""
+    og, *ovs = (o[:, 0, :] for o in outs)
+    oc = jnp.sum((og != PAD_GROUP).astype(jnp.int32), axis=-1)
+    return og, dict(zip(combiners, ovs)), oc
+
+
 def _sort_panes_kernel(g_ref, k_ref, og_ref, ok_ref):
-    g, k = common.bitonic_sort_tile((g_ref[0, :], k_ref[0, :]), num_keys=2)
-    og_ref[0, :] = g
-    ok_ref[0, :] = k
+    g, k = common.bitonic_sort_tile((g_ref[0], k_ref[0]), num_keys=2)
+    og_ref[0] = g
+    ok_ref[0] = k
 
 
 def sort_panes_pallas(panes_g, panes_k, *, interpret: bool):
-    """Prologue: sort each [1, WA] pane tile once by (group, key)."""
+    """Prologue: sort each WA-wide pane once by (group, key).  Takes
+    ``[NP, WA]`` panes, returns them sorted in the ``[NP, 1, WA]`` row
+    layout :func:`swag_pallas_panes` reads."""
     np_, wa = panes_g.shape
-    block = pl.BlockSpec((1, wa), lambda i: (i, 0))
+    block = _row_block(wa)
     return pl.pallas_call(
         _sort_panes_kernel,
         grid=(np_,),
         in_specs=[block, block],
         out_specs=[block, block],
         out_shape=[
-            jax.ShapeDtypeStruct((np_, wa), jnp.int32),
-            jax.ShapeDtypeStruct((np_, wa), panes_k.dtype),
+            jax.ShapeDtypeStruct((np_, 1, wa), jnp.int32),
+            jax.ShapeDtypeStruct((np_, 1, wa), panes_k.dtype),
         ],
         interpret=interpret,
-    )(panes_g, panes_k)
+    )(_rows(panes_g), _rows(panes_k))
 
 
 def _pane_kernel(*refs, p: int, wa: int, combiners: dict):
     g_refs, k_refs = refs[:p], refs[p:2 * p]
-    og_ref, *ov_refs, oc_ref = refs[2 * p:]
-    g = jnp.concatenate([r[0, :] for r in g_refs], axis=-1)
-    k = jnp.concatenate([r[0, :] for r in k_refs], axis=-1)
+    g = jnp.concatenate([r[0] for r in g_refs], axis=-1)
+    k = jnp.concatenate([r[0] for r in k_refs], axis=-1)
     # panes are presorted: merge network instead of a re-sort
     g, k = common.bitonic_merge_tile((g, k), num_keys=2, run=wa)
-    cg, vals, cnt = _multi_tails_in_tile(g, k, combiners)
-    og_ref[0, :] = cg
-    for name, ov_ref in zip(combiners, ov_refs):
-        ov_ref[0, :] = vals[name]
-    oc_ref[0, 0] = cnt[0]
+    _write_tails(refs[2 * p:], *_multi_tails_in_tile(g, k, combiners),
+                 combiners)
 
 
 def _resolve_ops(ops) -> dict:
@@ -176,36 +196,74 @@ def _resolve_ops(ops) -> dict:
 def swag_pallas_panes(panes_g, panes_k, ops, *, p: int, interpret: bool):
     """Window pass over presorted panes — one merge, N combiner tails.
 
-    ``panes_*``: [NP, WA] sorted panes (from :func:`sort_panes_pallas`);
-    window ``i`` merges pane rows ``i .. i+p-1`` — expressed as ``p``
-    overlapping BlockSpecs over the same operand, one per pane offset.
-    ``ops`` is one op name or a tuple of names (the fused multi-op path:
-    the pane framing, the merge network and the compaction run once; each
-    extra op adds only its scan + one value column).  Returns
-    ``(og, {name: ov}, oc)``.
+    ``panes_*``: ``[NP, 1, WA]`` sorted panes (from
+    :func:`sort_panes_pallas`); window ``i`` merges pane rows
+    ``i .. i+p-1`` — expressed as ``p`` overlapping BlockSpecs over the
+    same operand, one per pane offset.  ``ops`` is one op name or a tuple
+    of names (the fused multi-op path: the pane framing, the merge network
+    and the compaction run once; each extra op adds only its scan + one
+    value column).  Returns ``(og, {name: ov}, oc)``.
     """
-    np_, wa = panes_g.shape
+    np_, _, wa = panes_g.shape
     nw = np_ - p + 1
     ws = p * wa
     combiners = _resolve_ops(ops)
 
     kern = functools.partial(_pane_kernel, p=p, wa=wa, combiners=combiners)
-    pane_specs = [pl.BlockSpec((1, wa), lambda i, off=off: (i + off, 0))
-                  for off in range(p)]
-    out_block = pl.BlockSpec((1, ws), lambda i: (i, 0))
-    cnt_block = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    og, *ovs, oc = pl.pallas_call(
+    pane_specs = [_row_block(wa, off) for off in range(p)]
+    outs = pl.pallas_call(
         kern,
         grid=(nw,),
         in_specs=pane_specs + pane_specs,
-        out_specs=[out_block] + [out_block] * len(combiners) + [cnt_block],
-        out_shape=[jax.ShapeDtypeStruct((nw, ws), jnp.int32)]
-        + [jax.ShapeDtypeStruct((nw, ws), _out_dtype(name, panes_k.dtype))
-           for name in combiners]
-        + [jax.ShapeDtypeStruct((nw, 1), jnp.int32)],
+        out_specs=[_row_block(ws)] * (1 + len(combiners)),
+        out_shape=_window_outputs(nw, ws, combiners, panes_k.dtype),
         interpret=interpret,
     )(*([panes_g] * p + [panes_k] * p))
-    return og, dict(zip(combiners, ovs)), oc[:, 0]
+    return _window_results(outs, combiners)
+
+
+def _direct_tails_in_tile(ck, cnt, names, key_dtype):
+    """Every DIRECT_OPS value of one compacted, key-sorted live prefix
+    (``cnt`` live lanes) as ``[1, 1]`` columns — the kernel rendering of
+    :func:`repro.core.panestore._direct_tails` with ``interpolate=False``."""
+    lane = common.lane_iota(ck)
+    live = lane < cnt
+    nonempty = cnt > 0
+    zero = jnp.zeros((), ck.dtype)
+    acc = get_combiner("sum").lift(jnp.zeros((), key_dtype)).dtype
+
+    def pick(i):
+        return jnp.sum(jnp.where(lane == i, ck, zero), axis=-1, keepdims=True)
+
+    def total():
+        return jnp.sum(jnp.where(live, ck, zero).astype(acc), axis=-1,
+                       keepdims=True)
+
+    out = {}
+    for name in names:
+        if name == "count":
+            out[name] = cnt
+        elif name == "sum":
+            out[name] = total()
+        elif name == "min":
+            out[name] = jnp.where(nonempty, pick(0), zero)
+        elif name == "max":
+            out[name] = jnp.where(nonempty, pick(cnt - 1), zero)
+        elif name == "mean":
+            out[name] = (total().astype(jnp.float32)
+                         / jnp.maximum(cnt, 1).astype(jnp.float32))
+        elif name == "median":
+            out[name] = jnp.where(nonempty,
+                                  pick(jnp.maximum(cnt - 1, 0) >> 1), zero)
+        elif name == "distinct_count":
+            prev = common.shift_right(ck, 1, _panestore._key_sentinel(
+                ck.dtype))
+            neq = (ck != prev) & live
+            out[name] = jnp.sum(neq.astype(jnp.int32), axis=-1,
+                                keepdims=True)
+        else:  # pragma: no cover - guarded by the registry
+            raise ValueError(f"{name} is not a direct replay op")
+    return out
 
 
 def _pergroup_kernel(k_ref, v_ref, *ov_refs, names, run):
@@ -223,15 +281,13 @@ def _pergroup_kernel(k_ref, v_ref, *ov_refs, names, run):
     of the global-window kernels, with the compaction network doing the
     work the PRRA's reverse butterfly does in hardware.
     """
-    k = k_ref[0, :]
-    vi = v_ref[0, :]
-    k, vi = common.bitonic_merge_tile((k, vi), num_keys=1, run=run)
+    k, vi = common.bitonic_merge_tile((k_ref[0], v_ref[0]), num_keys=1,
+                                      run=run)
     sentinel = _panestore._key_sentinel(k.dtype)
     (ck,), cnt = common.butterfly_compact(vi != 0, (k,), (sentinel,))
-    vals = _panestore._direct_tails(ck, cnt[0], names, key_dtype=k.dtype,
-                                    interpolate=False)
+    vals = _direct_tails_in_tile(ck, cnt, names, k.dtype)
     for name, ov_ref in zip(names, ov_refs):
-        ov_ref[0, 0] = vals[name]
+        ov_ref[0] = vals[name]
 
 
 def _pergroup_out_dtype(name: str, key_dtype):
@@ -255,149 +311,149 @@ def pergroup_replay_pallas(run_keys, run_valid, ops, *, run: int,
     r, L = run_keys.shape
     names = (ops,) if isinstance(ops, str) else tuple(ops)
     kern = functools.partial(_pergroup_kernel, names=names, run=run)
-    block = pl.BlockSpec((1, L), lambda i: (i, 0))
-    out_block = pl.BlockSpec((1, 1), lambda i: (i, 0))
     outs = pl.pallas_call(
         kern,
         grid=(r,),
-        in_specs=[block, block],
-        out_specs=[out_block] * len(names),
+        in_specs=[_row_block(L)] * 2,
+        out_specs=[_row_block(1)] * len(names),
         out_shape=[jax.ShapeDtypeStruct(
-            (r, 1), _pergroup_out_dtype(name, run_keys.dtype))
+            (r, 1, 1), _pergroup_out_dtype(name, run_keys.dtype))
             for name in names],
         interpret=interpret,
-    )(run_keys, run_valid)
-    return {name: o[:, 0] for name, o in zip(names, outs)}
+    )(_rows(run_keys), _rows(run_valid))
+    return {name: o[:, 0, 0] for name, o in zip(names, outs)}
 
 
-def _pergroup_fused_kernel(ck_ref, slot_ref, lane_ref, seq_ref, own_ref,
-                           cnt_ref, lo_ref, sm_ref, ug_ref, *refs,
-                           names, c, wa):
-    """One WA chunk of the fused push+replay pass: the pane-store ring
+#: per-slot partials the fused per-group kernel emits, by op
+_SLOT_PARTIALS = {"count": ("count",), "sum": ("count", "sum"),
+                  "mean": ("count", "sum"), "min": ("count", "min"),
+                  "max": ("count", "max")}
+
+
+def _pergroup_fused_kernel(ck_ref, slot_ref, lane_ref, seq_ref, cnt_ref,
+                           lo_ref, sm_ref, own_ref, *refs, parts, wa):
+    """One WA chunk of the fused push + partial pass: the pane-store ring
     buffers live in VMEM scratch across the whole sequential grid, so each
     chunk is ONE dispatch — scalar writes into the resident store, the
-    close-sort epilogue, then the per-pane partial evaluation — with no
-    store round trip through HBM between update and replay.
+    close-sort epilogue, then the per-slot partial aggregates — with no
+    store round trip through HBM between update and evaluation.
+
+    The store is held transposed, ``[WA, C]``: slots on lanes, a slot's
+    tuples down the sublanes.  Per-slot partials are then sublane
+    reductions that land directly in the ``[1, C]`` row layout of the
+    directory snapshots, and a tuple write is one dynamic-row
+    read-modify-write with a lane mask.  The tuple coordinates arrive as
+    SMEM scalars.
 
     The *placement* decisions (slot/lane/seq per tuple, close/retire/evict
     fallout as directory snapshots) arrive precomputed by the XLA
-    directory scan of :func:`repro.core.swag.pergroup_write_plan` — the
-    same bookkeeping-in-XLA split the gather path uses.  The evaluation
-    mirrors :func:`repro.core.panestore._replay_partials` formula-for-
-    formula, so outputs are bit-exact vs the reference partial path.
+    directory scan of :func:`repro.core.swag.pergroup_write_plan`; the
+    per-group combine of the slot partials runs in XLA after the kernel
+    (:func:`repro.kernels.swag.ops._combine_slot_partials`).
 
     The close-sort runs lexicographically on ``(key, seq)``: lanes of a
     closing pane hold strictly increasing seqs in arrival order, so the
-    2-key bitonic sort *is* the store's stable-by-key argsort (and keeps
-    values inside the comparisons, which XLA:CPU needs to compile the
-    network in reasonable time — see ``_swag_shared_partials``).
+    2-key bitonic sort *is* the store's stable-by-key argsort.
     """
-    out_refs = refs[:len(names)]
-    kk_s, ss_s = refs[len(names):]
+    out_refs = refs[:len(parts)]
+    kk_s, ss_s = refs[len(parts):]
+    c = kk_s.shape[1]
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
-        kk_s[...] = jnp.zeros((c, wa), kk_s.dtype)
-        ss_s[...] = jnp.zeros((c, wa), jnp.int32)
+        kk_s[...] = jnp.zeros(kk_s.shape, kk_s.dtype)
+        ss_s[...] = jnp.zeros(ss_s.shape, jnp.int32)
 
-    def write(i, carry):
-        s = slot_ref[0, i]
-        l = lane_ref[0, i]
-        kk_s[s, l] = ck_ref[0, i]
-        ss_s[s, l] = seq_ref[0, i]
+    slot_lane = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
+
+    def write(t, carry):
+        row = pl.ds(lane_ref[0, 0, t], 1)
+        hit = slot_lane == slot_ref[0, 0, t]
+        kk_s[row, :] = jnp.where(hit, ck_ref[0, 0, t], kk_s[row, :])
+        ss_s[row, :] = jnp.where(hit, seq_ref[0, 0, t], ss_s[row, :])
         return carry
 
     jax.lax.fori_loop(0, wa, write, 0)
 
     kk = kk_s[...]
     ss = ss_s[...]
-    sk, sq = common.bitonic_sort_tile((kk, ss), num_keys=2)
-    closing = (sm_ref[0, :] != 0)[:, None]
+    sk, sq = common.bitonic_sort_tile((kk, ss), num_keys=2, axis=0)
+    closing = sm_ref[0] != 0
     kk = jnp.where(closing, sk, kk)
     ss = jnp.where(closing, sq, ss)
     kk_s[...] = kk
     ss_s[...] = ss
 
-    owner = own_ref[0, :]
-    count = cnt_ref[0, :]
-    lo = lo_ref[0, :]
-    ug = ug_ref[0, :]
-    occ = owner != PAD_GROUP
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (c, wa), 1)
-    live = occ[:, None] & (lanes < count[:, None]) & (ss >= lo[:, None])
-    rows = ((ug[:, None] == owner[None, :]) & occ[None, :]
-            & (ug[:, None] != PAD_GROUP))
-
+    lanes = jax.lax.broadcasted_iota(jnp.int32, kk.shape, 0)
+    live = ((own_ref[0] != PAD_GROUP) & (lanes < cnt_ref[0])
+            & (ss >= lo_ref[0]))
     key_dtype = kk.dtype
-    hi = _panestore._key_sentinel(key_dtype)
-    lo_sent = (jnp.iinfo(key_dtype).min
-               if jnp.issubdtype(key_dtype, jnp.integer) else -jnp.inf)
-    pc = jnp.sum(live.astype(jnp.int32), axis=1)
-    cnt = jnp.sum(jnp.where(rows, pc[None, :], 0), axis=1)
-    rsum = None
-    if any(nm in ("sum", "mean") for nm in names):
-        acc = get_combiner("sum").lift(jnp.zeros((), key_dtype)).dtype
-        psum = jnp.sum(jnp.where(live, kk, 0).astype(acc), axis=1)
-        rsum = jnp.sum(jnp.where(rows, psum[None, :],
-                                 jnp.zeros((), acc)), axis=1)
-    for name, ov_ref in zip(names, out_refs):
-        if name == "count":
-            ov_ref[0, :] = cnt
-        elif name == "sum":
-            ov_ref[0, :] = rsum
-        elif name == "mean":
-            ov_ref[0, :] = (rsum.astype(jnp.float32)
-                            / jnp.maximum(cnt, 1).astype(jnp.float32))
-        elif name == "min":
-            pmin = jnp.min(jnp.where(live, kk, hi), axis=1)
-            v = jnp.min(jnp.where(rows, pmin[None, :], hi), axis=1)
-            ov_ref[0, :] = jnp.where(cnt > 0, v, jnp.zeros(
-                (), key_dtype)).astype(key_dtype)
-        elif name == "max":
-            pmax = jnp.max(jnp.where(live, kk, lo_sent), axis=1)
-            v = jnp.max(jnp.where(rows, pmax[None, :], lo_sent), axis=1)
-            ov_ref[0, :] = jnp.where(cnt > 0, v, jnp.zeros(
-                (), key_dtype)).astype(key_dtype)
-        else:  # pragma: no cover - routed by partial_path_names
-            raise ValueError(f"{name} is not a partial-path op")
+    row = pl.ds(pl.program_id(0) % 8, 1)
+    for part, o_ref in zip(parts, out_refs):
+        if part == "count":
+            v = jnp.sum(live.astype(jnp.int32), axis=0, keepdims=True)
+        elif part == "sum":
+            acc = get_combiner("sum").lift(jnp.zeros((), key_dtype)).dtype
+            v = jnp.sum(jnp.where(live, kk, 0).astype(acc), axis=0,
+                        keepdims=True)
+        elif part == "min":
+            v = jnp.min(jnp.where(live, kk, _panestore._key_sentinel(
+                key_dtype)), axis=0, keepdims=True)
+        else:  # max
+            v = jnp.max(jnp.where(live, kk, _min_sentinel(key_dtype)),
+                        axis=0, keepdims=True)
+        o_ref[row, :] = v
 
 
-def pergroup_fused_pallas(chunk_keys, slots, lanes, seqs, own_s, cnt_s,
-                          lo_s, sortmask, ugroups, ops, *, interpret):
-    """Fused push+replay over per-group pane chunks: the ring buffers stay
-    VMEM-resident across the sequential ``grid=(NE,)`` (Pallas scratch
-    persists between grid steps), so the historical per-chunk
-    update-store -> gather -> replay HBM round trip collapses into one
-    launch for the whole stream.
+def _min_sentinel(dtype):
+    return (jnp.iinfo(dtype).min if jnp.issubdtype(dtype, jnp.integer)
+            else -jnp.inf)
+
+
+def pergroup_slot_partials_pallas(chunk_keys, slots, lanes, seqs, own_s,
+                                  cnt_s, lo_s, sortmask, ops, *, interpret):
+    """Fused push + per-slot partials over per-group pane chunks: the ring
+    buffers stay VMEM-resident across the sequential ``grid=(NE,)``
+    (Pallas scratch persists between grid steps), so the historical
+    per-chunk update-store -> gather -> replay HBM round trip collapses
+    into one launch for the whole stream.
 
     Inputs are :func:`repro.core.swag.pergroup_write_plan` outputs
     (``chunk_keys/slots/lanes/seqs`` ``[NE, WA]``; directory snapshots
     ``[NE, C]``); ``ops`` are partial-path names.  Returns
-    ``{name: [NE, C]}`` values (mask with the plan's ``num`` outside).
+    ``{part: [NE, C]}`` per-slot partials for ``part`` in ``count``,
+    ``sum``, ``min``, ``max`` as the ops need them (eight chunks' rows per
+    output block, so the HBM writes are whole tiles).
     """
     ne, wa = chunk_keys.shape
     c = own_s.shape[1]
     names = (ops,) if isinstance(ops, str) else tuple(ops)
-    from jax.experimental.pallas import tpu as pltpu
+    parts = tuple(dict.fromkeys(p for nm in names
+                                for p in _SLOT_PARTIALS[nm]))
+    key_dtype = chunk_keys.dtype
+    acc = get_combiner("sum").lift(jnp.zeros((), key_dtype)).dtype
+    dtypes = {"count": jnp.int32, "sum": acc, "min": key_dtype,
+              "max": key_dtype}
+    ne8 = -(-ne // 8) * 8
 
-    kern = functools.partial(_pergroup_fused_kernel, names=names, c=c, wa=wa)
-    wblock = pl.BlockSpec((1, wa), lambda i: (i, 0))
-    cblock = pl.BlockSpec((1, c), lambda i: (i, 0))
+    kern = functools.partial(_pergroup_fused_kernel, parts=parts, wa=wa)
+    sblock = pl.BlockSpec((1, 1, wa), lambda i: (i, 0, 0),
+                          memory_space=pltpu.SMEM)
+    oblock = pl.BlockSpec((8, c), lambda i: (i // 8, 0))
     outs = pl.pallas_call(
         kern,
         grid=(ne,),
-        in_specs=[wblock] * 4 + [cblock] * 5,
-        out_specs=[cblock] * len(names),
-        out_shape=[jax.ShapeDtypeStruct(
-            (ne, c), _pergroup_out_dtype(name, chunk_keys.dtype))
-            for name in names],
-        scratch_shapes=[pltpu.VMEM((c, wa), chunk_keys.dtype),
-                        pltpu.VMEM((c, wa), jnp.int32)],
+        in_specs=[sblock] * 4 + [_row_block(c)] * 4,
+        out_specs=[oblock] * len(parts),
+        out_shape=[jax.ShapeDtypeStruct((ne8, c), dtypes[p]) for p in parts],
+        scratch_shapes=[pltpu.VMEM((wa, c), key_dtype),
+                        pltpu.VMEM((wa, c), jnp.int32)],
         interpret=interpret,
-    )(chunk_keys, slots.astype(jnp.int32), lanes.astype(jnp.int32),
-      seqs.astype(jnp.int32), own_s, cnt_s, lo_s,
-      sortmask.astype(jnp.int32), ugroups)
-    return {name: o for name, o in zip(names, outs)}
+    )(_rows(chunk_keys), _rows(slots.astype(jnp.int32)),
+      _rows(lanes.astype(jnp.int32)), _rows(seqs.astype(jnp.int32)),
+      _rows(cnt_s), _rows(lo_s), _rows(sortmask.astype(jnp.int32)),
+      _rows(own_s))
+    return {p: o[:ne] for p, o in zip(parts, outs)}
 
 
 def _twostack_kernel(kf_ref, vf_ref, kb_ref, vb_ref, *out_refs, names):
@@ -407,16 +463,19 @@ def _twostack_kernel(kf_ref, vf_ref, kb_ref, vb_ref, *out_refs, names):
     op's identity) — the flip of Tangwongsan et al.'s two-stack algorithm
     as log2(wcap) Hillis–Steele sweeps in VMEM.  The scan body is the
     *same* code the reference strategy runs batched
-    (:func:`repro.core.twostack.flip_scans`)."""
+    (:func:`repro.core.twostack.flip_scans`), with the kernel's roll-based
+    shifts."""
     from repro.core import twostack as _twostack
 
-    kf, vf = kf_ref[0, :], vf_ref[0, :] != 0
-    kb, vb = kb_ref[0, :], vb_ref[0, :] != 0
-    scans = _twostack.flip_scans(kf, vf, kb, vb, names, kf.dtype)
+    kf, vf = kf_ref[0], vf_ref[0] != 0
+    kb, vb = kb_ref[0], vb_ref[0] != 0
+    scans = _twostack.flip_scans(kf, vf, kb, vb, names, kf.dtype,
+                                 shift_left=common.shift_left,
+                                 shift_right=common.shift_right)
     for i, name in enumerate(names):
         fsuf, bpre = scans[name]
-        out_refs[2 * i][0, :] = fsuf
-        out_refs[2 * i + 1][0, :] = bpre
+        out_refs[2 * i][0] = fsuf
+        out_refs[2 * i + 1][0] = bpre
 
 
 def _state_dtype(name: str, key_dtype):
@@ -433,11 +492,11 @@ def twostack_flip_pallas(kf, vf, kb, vb, names, *, interpret: bool):
     ne, wcap = kf.shape
     names = tuple(names)
     kern = functools.partial(_twostack_kernel, names=names)
-    block = pl.BlockSpec((1, wcap), lambda i: (i, 0))
+    block = _row_block(wcap)
     out_shape = []
     for name in names:
         dt = _state_dtype(name, kf.dtype)
-        out_shape += [jax.ShapeDtypeStruct((ne, wcap), dt)] * 2
+        out_shape += [jax.ShapeDtypeStruct((ne, 1, wcap), dt)] * 2
     outs = pl.pallas_call(
         kern,
         grid=(ne,),
@@ -445,8 +504,9 @@ def twostack_flip_pallas(kf, vf, kb, vb, names, *, interpret: bool):
         out_specs=[block] * (2 * len(names)),
         out_shape=out_shape,
         interpret=interpret,
-    )(kf, vf.astype(jnp.int32), kb, vb.astype(jnp.int32))
-    return {name: (outs[2 * i], outs[2 * i + 1])
+    )(_rows(kf), _rows(vf.astype(jnp.int32)), _rows(kb),
+      _rows(vb.astype(jnp.int32)))
+    return {name: (outs[2 * i][:, 0, :], outs[2 * i + 1][:, 0, :])
             for i, name in enumerate(names)}
 
 
@@ -458,17 +518,13 @@ def swag_pallas(frames_g, frames_k, ops, *, interpret: bool):
     combiners = _resolve_ops(ops)
 
     kern = functools.partial(_kernel, combiners=combiners)
-    block = pl.BlockSpec((1, ws), lambda i: (i, 0))
-    cnt_block = pl.BlockSpec((1, 1), lambda i: (i, 0))
-    og, *ovs, oc = pl.pallas_call(
+    block = _row_block(ws)
+    outs = pl.pallas_call(
         kern,
         grid=(nw,),
         in_specs=[block, block],
-        out_specs=[block] + [block] * len(combiners) + [cnt_block],
-        out_shape=[jax.ShapeDtypeStruct((nw, ws), jnp.int32)]
-        + [jax.ShapeDtypeStruct((nw, ws), _out_dtype(name, frames_k.dtype))
-           for name in combiners]
-        + [jax.ShapeDtypeStruct((nw, 1), jnp.int32)],
+        out_specs=[block] * (1 + len(combiners)),
+        out_shape=_window_outputs(nw, ws, combiners, frames_k.dtype),
         interpret=interpret,
-    )(frames_g, frames_k)
-    return og, dict(zip(combiners, ovs)), oc[:, 0]
+    )(_rows(frames_g), _rows(frames_k))
+    return _window_results(outs, combiners)

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.engine import PAD_GROUP, _deprecated
+from repro.core.panestore import _key_sentinel
 from repro.core.swag import frame_panes, frame_windows, num_windows, \
     resolve_panes
 from repro.kernels import common as _common
@@ -125,8 +126,9 @@ def _swag_pergroup_kernel_exec(groups, keys, *, spec, ops,
     * **partial-fused** (every op on the partial path): ONE
       ``pallas_call`` over the whole stream — the ring buffers live in
       VMEM scratch across the sequential chunk grid, each step fusing the
-      store update (writes + close-sort epilogue) with the per-pane
-      partial evaluation.  No per-chunk store round trip through HBM.
+      store update (writes + close-sort epilogue) with the per-slot
+      partial aggregates.  No per-chunk store round trip through HBM; the
+      fold of slot partials into per-group values runs in XLA after it.
     * **merge-replay** (median/distinct_count present, or float
       sum/mean): the classic gather path — store push + pane gather in
       XLA, one ``pallas_call`` (grid over evaluation x group rows) for
@@ -155,9 +157,11 @@ def _swag_pergroup_kernel_exec(groups, keys, *, spec, ops,
         slots, lanes, seqs, own_s, cnt_s, lo_s, sortmask, ugroups, num = \
             pergroup_write_plan(spec, groups)
         ck = frame_panes(keys, spec.wa, ne)
-        ovs = _k.pergroup_fused_pallas(
-            ck, slots, lanes, seqs, own_s, cnt_s, lo_s, sortmask, ugroups,
-            names, interpret=interpret)
+        parts = _k.pergroup_slot_partials_pallas(
+            ck, slots, lanes, seqs, own_s, cnt_s, lo_s, sortmask, names,
+            interpret=interpret)
+        ovs = _combine_slot_partials(own_s, ugroups, parts, names,
+                                     keys.dtype)
         valid = jnp.arange(c)[None, :] < num[:, None]
         values = {name: jnp.where(valid, v, jnp.zeros((), v.dtype))
                   for name, v in ovs.items()}
@@ -179,6 +183,44 @@ def _swag_pergroup_kernel_exec(groups, keys, *, spec, ops,
               for name, v in ovs.items()}
     og = jnp.where(valid, runs.groups, PAD_GROUP)
     return og, values, valid, runs.num_groups
+
+
+def _combine_slot_partials(own_s, ugroups, parts, names, key_dtype):
+    """Per-slot partials ``{part: [NE, C]}`` -> per-group values
+    ``{name: [NE, C]}`` in the evaluation directory's row order: each
+    directory row ``u`` folds the slots its group owns (the per-pane
+    partial formulas of :func:`repro.core.panestore._replay_partials`).
+    Chunks are folded in batches so the ``[C, C]`` ownership masks stay
+    bounded."""
+    hi = _key_sentinel(key_dtype)
+    lo_sent = (jnp.iinfo(key_dtype).min
+               if jnp.issubdtype(key_dtype, jnp.integer) else -jnp.inf)
+
+    def chunk(xs):
+        owner, ug, part = xs
+        rows = ((ug[:, None] == owner[None, :]) & (owner != PAD_GROUP)[None, :]
+                & (ug != PAD_GROUP)[:, None])
+        cnt = jnp.sum(jnp.where(rows, part["count"][None, :], 0), axis=1)
+        out = {}
+        for name in names:
+            if name == "count":
+                out[name] = cnt
+            elif name in ("sum", "mean"):
+                psum = part["sum"]
+                rsum = jnp.sum(jnp.where(rows, psum[None, :],
+                                         jnp.zeros((), psum.dtype)), axis=1)
+                out[name] = rsum if name == "sum" else (
+                    rsum.astype(jnp.float32)
+                    / jnp.maximum(cnt, 1).astype(jnp.float32))
+            else:
+                fill = hi if name == "min" else lo_sent
+                red = jnp.min if name == "min" else jnp.max
+                v = red(jnp.where(rows, part[name][None, :], fill), axis=1)
+                out[name] = jnp.where(cnt > 0, v, jnp.zeros(
+                    (), key_dtype)).astype(key_dtype)
+        return out
+
+    return jax.lax.map(chunk, (own_s, ugroups, parts), batch_size=16)
 
 
 @functools.partial(jax.jit, static_argnames=("ops", "interpret"))

@@ -479,9 +479,9 @@ def plan(query: Query, *, backend: str | None = None, num_shards: int = 1,
     name = _registry.resolve_backend(backend)
     note = ""
     if name == "auto":
-        name = _registry.choose_backend(query, devices,
-                                        num_shards=num_shards)
-        note = "auto"
+        name, how = _registry.choose_backend_why(query, devices,
+                                                 num_shards=num_shards)
+        note = f"auto ({how})"
     reason = _registry.get_backend(name).supports(query)
     if reason is not None:
         raise _registry.unsupported_error(name, reason)
@@ -496,11 +496,11 @@ def plan(query: Query, *, backend: str | None = None, num_shards: int = 1,
             # an *auto*-chosen kernel backend must not turn a shardable
             # query into a plan failure — fall back to the total reference
             # backend (an explicitly requested backend still raises)
-            if note != "auto" or name == "reference":
+            if not note.startswith("auto") or name == "reference":
                 raise
             name = "reference"
             _validate_sharded(query, name, num_shards)
-            note = "auto; kernel backend cannot shard this query"
+            note += "; kernel backend cannot shard this query"
         stages = ("partition", "local", "merge", "finalize")
 
     path = ("stream" if query.streaming

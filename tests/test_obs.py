@@ -374,6 +374,17 @@ def test_choose_backend_consults_metrics():
     # the slower measured candidate loses even when observed more recently
     METRICS.observe("reference", fp, tuples=10, seconds=1.0)
     assert choose_backend(q) == "pallas-panestore"
+    assert plan(q).note == "auto (measured)"
+    # on an accelerator the vote is among the kernel backends only: a
+    # faster reference cell never routes a kernel-capable query off them
+    METRICS.observe("reference", fp, tuples=10 ** 12, seconds=1.0)
+    assert choose_backend(q) == "reference"
+
+    class _Tpu:
+        platform = "tpu"
+
+    assert choose_backend(q, devices=[_Tpu()]) == "pallas-panestore"
+    assert plan(q, devices=[_Tpu()]).note == "auto (accelerator)"
     METRICS.reset()
     assert choose_backend(q) == "reference"
 

@@ -9,7 +9,9 @@ is bit-identical to the re-sorted one).
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 import pytest
 from hypothesis import given, settings, strategies as st
 
@@ -63,12 +65,20 @@ def test_merge_presorted_lexicographic(rng):
 
 @pytest.mark.parametrize("run,p", [(8, 4), (32, 2), (16, 8)])
 def test_bitonic_merge_tile_matches_sorter(run, p, rng):
-    """Gather-free tile merge == the gather-based sorter merge == np.sort."""
+    """Gather-free tile merge == the gather-based sorter merge == np.sort.
+    The tile primitives lower only inside a kernel, so the merge runs in an
+    interpret-mode ``pallas_call`` over the whole batch."""
     batch = 3
     x = np.stack([np.concatenate(
         [np.sort(rng.integers(0, 999, run)) for _ in range(p)])
         for _ in range(batch)]).astype(np.int32)
-    (mt,) = common.bitonic_merge_tile((jnp.array(x),), num_keys=1, run=run)
+
+    def kernel(x_ref, o_ref):
+        (o_ref[...],) = common.bitonic_merge_tile((x_ref[...],), num_keys=1,
+                                                  run=run)
+
+    mt = pl.pallas_call(kernel, out_shape=jax.ShapeDtypeStruct(
+        x.shape, jnp.int32), interpret=True)(jnp.array(x))
     for r in range(batch):
         np.testing.assert_array_equal(np.array(mt[r]), np.sort(x[r]))
 
