@@ -13,7 +13,13 @@ same data:
     median;
   * grouped aggregate: 2^24 group-sorted tuples over 2^16 groups;
   * per-group windows: ``ws_per_group=1024``, ``wa=128``, 256 groups,
-    2^20 tuples;
+    2^20 tuples (the per-pane partial path); and the same window with
+    median and distinct count (the merge-replay path) on 2^16 tuples over
+    16 groups — that path replays every store slot at every evaluation,
+    so its memory grows with evaluations x capacity x window;
+  * event-time windows, ``Window(range=2048, slide=512)`` over 2^20
+    tuples with random timestamps: ungrouped min/max/sum/count (the
+    two-stack), and grouped median and distinct count (per-window replay);
   * streaming windowed push: four pushes of 2^20 tuples through
     ``StreamingAggregator`` (reference backend by declared capability),
     checked against the batch per-group reference at each push boundary.
@@ -26,7 +32,7 @@ line, when JAX finds no TPU, when a kernel phase is planned onto
 line of a passing run is one JSON object naming the device.
 
 ``--four-chips`` runs only the sharded windowed (the flat SWAG window,
-2^22 tuples) and grouped (2^16 tuples over 2^8 groups) queries over a
+2^22 tuples) and grouped (2^24 tuples over 2^16 groups) queries over a
 4-device mesh, each compared bit for bit with the same query on one device,
 and prints the devices each shard's local results lived on.
 
@@ -68,16 +74,17 @@ class Sizes:
     pergroup_groups: int = 256
     pergroup_ws: int = 1024
     pergroup_wa: int = 128
+    pergroup_merge_n: int = 2 ** 16
+    pergroup_merge_groups: int = 16
+    time_n: int = 2 ** 20
+    time_span: int = 2 ** 21
+    time_range: int = 2048
+    time_slide: int = 512
+    time_groups: int = 64
     stream_push: int = 2 ** 20
     stream_pushes: int = 4
     stream_groups: int = 64
     key_range: int = 1024
-    # --four-chips grouped query: its combine tree runs op by op through
-    # the reference engine's scans on table widths that double per round;
-    # at 2^20 tuples the phase did not finish within 480 s on four v5e
-    # chips, so it runs small and after the windowed query
-    sharded_grouped_n: int = 2 ** 16
-    sharded_grouped_groups: int = 2 ** 8
 
 
 def _log(**fields) -> None:
@@ -135,7 +142,8 @@ class Reference:
         from repro.query import execute
         self.device = jax.devices("cpu")[0] if on_cpu else None
         self._fn = jax.jit(lambda g, k: execute(
-            query, g, k, backend="reference", **kwargs)[0])
+            query, g, k, backend="reference", **kwargs)[0]._replace(
+                stats=None))
 
     def __call__(self, g, k):
         if self.device is not None:
@@ -147,9 +155,12 @@ class Reference:
         return "cpu" if self.device is not None else "chip"
 
 
-def _kernel_phase(name: str, query, batches, ref: Reference) -> None:
+def _kernel_phase(name: str, query, batches, ref: Reference,
+                  **kwargs) -> None:
     """Plan ``query`` with ``auto`` (it must land on a kernel backend), run
-    it through ``execute`` on every batch, and compare with ``ref``."""
+    it through ``execute`` on every batch, and compare with ``ref``.
+    ``kwargs`` go to ``execute`` (event-time timestamps: concrete host
+    arrays, closed over, since the window layout is a static shape)."""
     from repro.query import execute, plan
 
     p = plan(query)
@@ -159,8 +170,8 @@ def _kernel_phase(name: str, query, batches, ref: Reference) -> None:
                            f"kernel backend")
     t0 = time.perf_counter()
     lowered = jax.jit(
-        lambda g, k: execute(p, g, k)[0]._replace(stats=None)).lower(
-            *batches[0])
+        lambda g, k: execute(p, g, k, **kwargs)[0]._replace(
+            stats=None)).lower(*batches[0])
     kernel = _has_kernel(lowered)
     compiled = lowered.compile()
     compile_s = time.perf_counter() - t0
@@ -203,19 +214,48 @@ def _grouped_phase(seed: int, sz: Sizes) -> None:
                          sz.key_range, sort=True)], Reference(q))
 
 
-def _pergroup_phase(seed: int, sz: Sizes) -> None:
-    """Per-group windows, with room in the store for every group's window.
-    The reference runs on the chip: on the CPU its per-slot ownership
-    masks are not fused away and outgrow host memory at this capacity."""
+def _pergroup_phases(seed: int, sz: Sizes) -> None:
+    """Per-group windows, with room in the store for every group's window:
+    the paper engine's ops (the per-pane partial path), then median and
+    distinct count (the merge-replay path).  The partial path's reference
+    runs on the chip: on the CPU its per-slot ownership masks are not
+    fused away and outgrow host memory.  The merge path's runs on the CPU:
+    XLA's TPU layout pads its replay rows past the chip's memory."""
+    from repro.kernels import registry
     from repro.query import Query, Window
 
-    cap = sz.pergroup_groups * (sz.pergroup_ws // sz.pergroup_wa + 1)
-    q = Query(PAPER_OPS, window=Window(ws=sz.pergroup_ws,
-                                       ws_per_group=sz.pergroup_ws,
-                                       wa=sz.pergroup_wa, capacity=cap))
-    _kernel_phase("per-group", q,
-                  [_data(seed, 20, sz.pergroup_n, sz.pergroup_groups,
-                         sz.key_range)], Reference(q, on_cpu=False))
+    for name, ops, n, groups, stream, ref_on_cpu in (
+            ("per-group", PAPER_OPS, sz.pergroup_n, sz.pergroup_groups, 20,
+             False),
+            ("per-group-merge", ("median", "dc"), sz.pergroup_merge_n,
+             sz.pergroup_merge_groups, 21, True)):
+        cap = groups * (sz.pergroup_ws // sz.pergroup_wa + 1)
+        q = Query(ops, window=Window(ws=sz.pergroup_ws,
+                                     ws_per_group=sz.pergroup_ws,
+                                     wa=sz.pergroup_wa, capacity=cap))
+        _log(phase=name, kernel_path=registry.pergroup_kernel_path(q))
+        _kernel_phase(name, q, [_data(seed, stream, n, groups,
+                                      sz.key_range)],
+                      Reference(q, on_cpu=ref_on_cpu))
+
+
+def _eventtime_phases(seed: int, sz: Sizes) -> None:
+    """Time-range windows over random (unsorted) timestamps, made on the
+    host: the window layout is computed there from concrete timestamps."""
+    import numpy as np
+
+    from repro.query import Query, Window, resolve_time_strategy
+
+    ts = np.random.default_rng([seed, 40]).integers(
+        0, sz.time_span, sz.time_n).astype(np.int32)
+    window = Window(range=sz.time_range, slide=sz.time_slide)
+    batch = [_data(seed, 41, sz.time_n, sz.time_groups, sz.key_range)]
+    for name, q in (
+            ("event-time", Query(PAPER_OPS, group_by=False, window=window)),
+            ("event-time-grouped", Query(("median", "dc"), window=window))):
+        _log(phase=name, strategy=resolve_time_strategy(q))
+        _kernel_phase(name, q, batch, Reference(q, timestamps=ts),
+                      timestamps=ts)
 
 
 def _stream_phase(seed: int, sz: Sizes) -> None:
@@ -268,29 +308,49 @@ def _stream_phase(seed: int, sz: Sizes) -> None:
 def run_one_chip(seed: int, sz: Sizes = Sizes()) -> None:
     _flat_phases(seed, sz)
     _grouped_phase(seed, sz)
-    _pergroup_phase(seed, sz)
+    _pergroup_phases(seed, sz)
+    _eventtime_phases(seed, sz)
     _stream_phase(seed, sz)
 
 
-def _record_shard_devices(log: list):
-    """Wrap the kernel entry points the sharded local phase calls, noting
-    the devices each shard's local results land on."""
+def _record_local_devices(log: list) -> None:
+    """Note the devices each kernel call of a sharded local phase leaves
+    its results on (the calls made inside the kernel-backend local phases
+    of ``repro.distributed.query_exec``; the merge stage's are not local)."""
+    from repro.distributed import query_exec as qx
     from repro.kernels.groupagg import ops as gops
     from repro.kernels.swag import ops as sops
 
-    def wrap(mod, attr):
+    local = [False]
+
+    def in_local(mod, attr):
         fn = getattr(mod, attr)
 
-        def recorded(*args, **kwargs):
+        def wrapped(*args, **kwargs):
+            local[0] = True
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                local[0] = False
+
+        setattr(mod, attr, wrapped)
+
+    def recorded(mod, attr):
+        fn = getattr(mod, attr)
+
+        def wrapped(*args, **kwargs):
             out = fn(*args, **kwargs)
-            leaf = jax.tree.leaves(out)[0]
-            log.append(sorted(str(d) for d in leaf.devices()))
+            if local[0]:
+                leaf = jax.tree.leaves(out)[0]
+                log.append(sorted(str(d) for d in leaf.devices()))
             return out
 
-        setattr(mod, attr, recorded)
+        setattr(mod, attr, wrapped)
 
-    wrap(gops, "_groupagg_kernel_exec")
-    wrap(sops, "_swag_kernel_exec")
+    in_local(qx, "_local_engine_tables")
+    in_local(qx, "_window_partitioned")
+    recorded(gops, "_groupagg_kernel_exec")
+    recorded(sops, "_swag_kernel_exec")
 
 
 def run_four_chips(seed: int, sz: Sizes = Sizes()) -> None:
@@ -302,14 +362,14 @@ def run_four_chips(seed: int, sz: Sizes = Sizes()) -> None:
                            f"{len(devices)}")
     mesh = jax.make_mesh((4,), ("shards",), devices=devices[:4])
     shard_log: list = []
-    _record_shard_devices(shard_log)
+    _record_local_devices(shard_log)
     cases = [
         ("sharded-window", Query(PAPER_OPS, window=Window(ws=sz.ws,
                                                           wa=sz.wa)),
          _data(seed, 31, sz.flat_n, sz.flat_groups, sz.key_range)),
         ("sharded-grouped", Query(PAPER_OPS),
-         _data(seed, 30, sz.sharded_grouped_n, sz.sharded_grouped_groups,
-               sz.key_range, sort=True)),
+         _data(seed, 30, sz.grouped_n, sz.grouped_groups, sz.key_range,
+               sort=True)),
     ]
     ok = True
     for name, q, (g, k) in cases:
@@ -317,20 +377,23 @@ def run_four_chips(seed: int, sz: Sizes = Sizes()) -> None:
         _log(phase=name, backend=p.backend, note=p.note, shards=4)
         if p.backend not in KERNEL_BACKENDS:
             raise SmokeFailure(f"{name}: planned onto {p.backend!r}")
-        del shard_log[:]
-        t0 = time.perf_counter()
-        got, _ = execute(q, g, k, mesh=mesh)
-        got = jax.block_until_ready(got)
-        sharded_s = time.perf_counter() - t0
+        walls = []
+        for _ in range(2):      # the first call compiles, eagerly
+            del shard_log[:]
+            t0 = time.perf_counter()
+            got = jax.block_until_ready(execute(q, g, k, mesh=mesh)[0])
+            walls.append(time.perf_counter() - t0)
         local_devices = list(shard_log)
         t0 = time.perf_counter()
-        want = jax.block_until_ready(execute(q, g, k)[0])
+        want = jax.block_until_ready(jax.jit(
+            lambda g, k: execute(q, g, k)[0]._replace(stats=None))(g, k))
         single_s = time.perf_counter() - t0
         bad = _mismatches(got, want)
         _log(phase=name, tuples=int(g.shape[0]),
              local_kernel_call_devices=local_devices,
              result_devices=sorted(str(d) for d in got.groups.devices()),
-             sharded_wall_s=sharded_s, single_device_wall_s=single_s,
+             sharded_first_call_s=walls[0], sharded_wall_s=walls[1],
+             single_device_jit_first_call_s=single_s,
              mismatches=bad, bit_identical=bad == 0)
         ok &= bad == 0
     if not ok:

@@ -223,28 +223,30 @@ def combine_partial_tables(a: PartialTable, b: PartialTable, ops, *,
     """Merge two per-range partial tables (``a`` the earlier range) — one
     node of the cross-device combine tree.
 
-    Both tables' rows are ascending unique group ids with ``PAD_GROUP``
-    tails, so one 2-key sort of the concatenated rows ((group, provenance)
-    — provenance keeps ``a`` before ``b`` within a group, which the
-    order-sensitive merges (dc's boundary rule, first/last) require) makes
-    equal groups adjacent; a segmented fold with each op's
+    Both tables' rows are ascending group ids with ``PAD_GROUP`` tails, so
+    their rows interleave by a stable two-run merge: each row's output
+    position is its own index plus its rank in the other table (``a`` first
+    within a group, which the order-sensitive merges — dc's boundary rule,
+    first/last — require).  That makes equal groups adjacent without a
+    general sort; a segmented fold with each op's
     :func:`repro.core.combiners.partial_combiner` then collapses them and
     the shared compaction re-packs the result.  Output width is the sum of
     the input widths (static shapes; real groups can never exceed that).
     """
     combiners = tuple(_resolve(op) for op in ops)
-    g = jnp.concatenate([a.groups, b.groups]).astype(jnp.int32)
-    tag = jnp.concatenate([
-        jnp.zeros(a.groups.shape, jnp.int32),
-        jnp.ones(b.groups.shape, jnp.int32)])
-    states = {
-        c.name: jax.tree.map(lambda x, y: jnp.concatenate([x, y]),
-                             a.states[c.name], b.states[c.name])
-        for c in combiners}
-    leaves, treedef = jax.tree.flatten(states)
-    sorted_ops = jax.lax.sort((g, tag, *leaves), num_keys=2, is_stable=True)
-    g = sorted_ops[0]
-    states = jax.tree.unflatten(treedef, sorted_ops[2:])
+    ga, gb = a.groups.astype(jnp.int32), b.groups.astype(jnp.int32)
+    na, nb = ga.shape[0], gb.shape[0]
+    pos_a = jnp.arange(na) + jnp.searchsorted(gb, ga, side="left")
+    pos_b = jnp.arange(nb) + jnp.searchsorted(ga, gb, side="right")
+
+    def merge(x, y):
+        out = jnp.zeros((na + nb,) + x.shape[1:], x.dtype)
+        out = out.at[pos_a].set(x, unique_indices=True)
+        return out.at[pos_b].set(y, unique_indices=True)
+
+    g = merge(ga, gb)
+    states = {c.name: jax.tree.map(merge, a.states[c.name], b.states[c.name])
+              for c in combiners}
 
     n = g.shape[0]
     starts = segscan.segment_starts(g)

@@ -41,14 +41,17 @@ kernels unchanged.  On the *reference* backend the local phase is SPMD
 (``shard_map``); the kernel-backend local phases currently run their
 per-shard kernels as a sequential gather-then-merge loop on the default
 device — same two-phase algebra and results, device placement pending
-(ROADMAP: "device-placed kernel local phases").
+(ROADMAP: "device-placed kernel local phases").  The grouped kernel
+backend also merges with its kernel (one group-by pass over the packed
+shard tables, :func:`_kernel_merge_tables`) instead of the combine tree.
 """
 from __future__ import annotations
 
+import functools
+import importlib
+
 import jax
 import jax.numpy as jnp
-
-import importlib
 
 from repro.core import engine as _engine
 from repro.core import sorter
@@ -143,23 +146,33 @@ def combine_tree(tables: _engine.PartialTable, ops, *, key_dtype,
         if counters is not None:
             round_width.append(tables.groups.shape[1])
             round_groups.append(jnp.sum(tables.num_groups))
-            round_bytes.append(sum(x.size * x.dtype.itemsize
-                                   for x in jax.tree_util.tree_leaves(tables)
-                                   if hasattr(x, "dtype")))
+            round_bytes.append(_table_bytes(tables))
     out = jax.tree.map(lambda x: x[0], tables)
     if counters is None:
         return out
+    return out, _put_round_counters(counters, round_width, round_groups,
+                                    round_bytes)
+
+
+def _table_bytes(table) -> int:
+    return sum(x.size * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(table)
+               if hasattr(x, "dtype"))
+
+
+def _put_round_counters(counters, widths, groups, nbytes):
+    """Record the merge stage's per-round telemetry (see
+    :func:`combine_tree`)."""
     from repro.obs import counters as _c
     counters = _c.put(counters, "combine_rounds",
-                      jnp.asarray(len(round_width), jnp.int32))
+                      jnp.asarray(len(widths), jnp.int32))
     counters = _c.put(counters, "combine_round_width",
-                      jnp.asarray(round_width, jnp.int32))
+                      jnp.asarray(widths, jnp.int32))
     counters = _c.put(counters, "combine_round_groups",
-                      (jnp.stack(round_groups) if round_groups
+                      (jnp.stack(groups) if groups
                        else jnp.zeros((0,), jnp.int32)))
-    counters = _c.put(counters, "combine_round_bytes",
-                      jnp.asarray(round_bytes, jnp.float32))
-    return out, counters
+    return _c.put(counters, "combine_round_bytes",
+                  jnp.asarray(nbytes, jnp.float32))
 
 
 def _trim_table(table: _engine.PartialTable, width: int
@@ -234,6 +247,52 @@ def _local_engine_tables(q, gs, ks, nvs, combiner_ops, mesh, backend, *,
     return _map_shards(local, mesh, args)
 
 
+def _kernel_merge_tables(tables: _engine.PartialTable, *, tile, interpret
+                         ) -> _engine.PartialTable:
+    """Merge stage of the kernel backend: one group-by kernel pass per op.
+
+    The shards are adjacent ranges of the group-sorted stream, so their
+    tables' live rows, packed in shard order, form one group-sorted run in
+    which only a group that spans a shard boundary repeats.  Folding that
+    run with each op's :func:`repro.core.combiners.partial_combiner` (for
+    KERNEL_STATE_OPS: sum/min/max of the states, counts summed) is the
+    whole merge — the same kernel as the local phase, instead of the
+    reference engine's combine tree.  The packed run is as wide as the
+    stream, which is the single-device layout."""
+    from repro.kernels.groupagg.ops import _groupagg_kernel_exec
+
+    s, width = tables.groups.shape
+    n = s * width
+    offsets = jnp.cumsum(tables.num_groups) - tables.num_groups
+    lanes = jnp.arange(width)[None, :]
+    dest = jnp.where(lanes < tables.num_groups[:, None],
+                     offsets[:, None] + lanes, n).reshape(-1)
+
+    def pack(x, fill):
+        return jnp.full((n + 1,), fill, x.dtype).at[dest].set(
+            x.reshape(-1), mode="drop")[:n]
+
+    groups = pack(tables.groups, PAD_GROUP)
+    states = {}
+    shared = None
+    for name, state in tables.states.items():
+        r = _groupagg_kernel_exec(groups, pack(state, 0),
+                                  _partial_combiner(name), tile=tile,
+                                  interpret=interpret)
+        states[name] = r.values
+        shared = shared or (r.groups, r.valid, r.num_groups)
+    return _engine.PartialTable(shared[0], states, shared[1], shared[2])
+
+
+@functools.lru_cache(maxsize=None)
+def _partial_combiner(name: str):
+    """One table-level combiner object per op name: the kernel entry point
+    takes it as a static argument, so a fresh one per call would
+    recompile the kernel every time."""
+    from repro.core.combiners import get_combiner, partial_combiner
+    return partial_combiner(get_combiner(name))
+
+
 def _engine_sharded(q, groups, keys, n_valid, *, num_shards, mesh, backend,
                     tile, interpret, counters=None):
     from repro.obs import trace as _trace
@@ -263,7 +322,14 @@ def _engine_sharded(q, groups, keys, n_valid, *, num_shards, mesh, backend,
                                           interpret=interpret)
             sp.attach(tables)
         with _trace.span("merge") as sp:
-            if counters is None:
+            if backend == "pallas":
+                table = _kernel_merge_tables(tables, tile=tile,
+                                             interpret=interpret)
+                if counters is not None:
+                    counters = _put_round_counters(
+                        counters, [n], [table.num_groups],
+                        [_table_bytes(table)])
+            elif counters is None:
                 table = combine_tree(tables, combiner_ops,
                                      key_dtype=keys.dtype)
             else:

@@ -215,9 +215,13 @@ def resolve_backend(explicit: str | None = None) -> str:
     return name
 
 
-def choose_backend(query, devices=None, num_shards: int = 1) -> str:
+def choose_backend(query, devices=None, num_shards: int = 1
+                   ) -> tuple[str, str]:
     """Resolve ``auto`` for one query: **measured-cost routing** over the
     capability-filtered candidates, with the static probe as fallback.
+    Returns ``(backend, how)``, ``how`` naming the rule that chose it
+    (``"measured"``, ``"cpu"`` or ``"accelerator"``) — the planner records
+    it on the plan's note, so every choice shows.
 
     The adaptive half: among the backends whose capability probe accepts
     the query, consult :class:`repro.obs.registry.MetricsRegistry` for
@@ -225,9 +229,7 @@ def choose_backend(query, devices=None, num_shards: int = 1) -> str:
     but only when **two or more** candidates have measured cells.  A
     single cell proves nothing about the alternatives (and on CPU it
     would usually be the reference path's own telemetry re-electing
-    itself), so anything less falls back to the static choice.  On an
-    accelerator the measured choice is made among the kernel backends
-    only: a query a kernel can run never lands on ``reference`` there.
+    itself), so anything less falls back to the static choice.
 
     The static probe: on CPU every kernel would run in Pallas interpret
     mode — a correctness tool, orders of magnitude slower than the
@@ -242,31 +244,24 @@ def choose_backend(query, devices=None, num_shards: int = 1) -> str:
     ``reference`` | ``pallas`` | ``pallas-panes`` locally, with its
     per-shard kernels unchanged.
     """
-    return choose_backend_why(query, devices, num_shards)[0]
-
-
-def choose_backend_why(query, devices=None, num_shards: int = 1
-                       ) -> tuple[str, str]:
-    """:func:`choose_backend` plus how the choice was made (``"measured"``,
-    ``"cpu"`` or ``"accelerator"``) — the planner records it on the
-    plan's note."""
     candidates = [name for name in ("pallas-panestore", "pallas-panes",
                                     "pallas", "reference")
                   if get_backend(name).supports(query) is None]
-    on_cpu = common.is_cpu(devices)
-    kernels = [name for name in candidates if name != "reference"]
-    among = candidates if on_cpu or not kernels else kernels
 
     # measured-cost routing (lazy import: repro.obs must stay importable
     # without the kernels package and vice versa)
     from repro.obs.registry import METRICS, query_fingerprint
     fp = query_fingerprint(query, num_shards=num_shards)
-    measured = [name for name in among if METRICS.tuples_per_s(name, fp)]
+    measured = [name for name in candidates
+                if METRICS.tuples_per_s(name, fp)]
     if len(measured) >= 2:
-        best = METRICS.best_backend(fp, among=among)
+        best = METRICS.best_backend(fp, among=candidates)
         if best is not None:
             return best, "measured"
 
-    if on_cpu:
+    if common.is_cpu(devices):
         return "reference", "cpu"
-    return (kernels or ["reference"])[0], "accelerator"
+    for name in candidates:
+        if name != "reference":
+            return name, "accelerator"
+    return "reference", "accelerator"

@@ -479,8 +479,8 @@ def plan(query: Query, *, backend: str | None = None, num_shards: int = 1,
     name = _registry.resolve_backend(backend)
     note = ""
     if name == "auto":
-        name, how = _registry.choose_backend_why(query, devices,
-                                                 num_shards=num_shards)
+        name, how = _registry.choose_backend(query, devices,
+                                             num_shards=num_shards)
         note = f"auto ({how})"
     reason = _registry.get_backend(name).supports(query)
     if reason is not None:

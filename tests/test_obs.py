@@ -360,33 +360,23 @@ def test_choose_backend_consults_metrics():
 
     METRICS.reset()
     # empty registry -> static probe (CPU: reference)
-    assert choose_backend(q) == "reference"
+    assert choose_backend(q)[0] == "reference"
     # a single measured cell proves nothing about the alternatives
     METRICS.observe("reference", fp, tuples=1_000, seconds=1.0)
-    assert choose_backend(q) == "reference"
+    assert choose_backend(q)[0] == "reference"
     # two measured candidates -> the numbers decide
     METRICS.observe("pallas-panestore", fp, tuples=50_000, seconds=1.0)
-    assert choose_backend(q) == "pallas-panestore"
+    assert choose_backend(q)[0] == "pallas-panestore"
     assert plan(q).backend == "pallas-panestore"    # auto plan follows
     # a (stale) cell for a backend that cannot run this query never wins
     METRICS.observe("pallas", fp, tuples=10_000_000, seconds=1.0)
-    assert choose_backend(q) == "pallas-panestore"
+    assert choose_backend(q)[0] == "pallas-panestore"
     # the slower measured candidate loses even when observed more recently
     METRICS.observe("reference", fp, tuples=10, seconds=1.0)
-    assert choose_backend(q) == "pallas-panestore"
+    assert choose_backend(q)[0] == "pallas-panestore"
     assert plan(q).note == "auto (measured)"
-    # on an accelerator the vote is among the kernel backends only: a
-    # faster reference cell never routes a kernel-capable query off them
-    METRICS.observe("reference", fp, tuples=10 ** 12, seconds=1.0)
-    assert choose_backend(q) == "reference"
-
-    class _Tpu:
-        platform = "tpu"
-
-    assert choose_backend(q, devices=[_Tpu()]) == "pallas-panestore"
-    assert plan(q, devices=[_Tpu()]).note == "auto (accelerator)"
     METRICS.reset()
-    assert choose_backend(q) == "reference"
+    assert choose_backend(q)[0] == "reference"
 
 
 # ---------------------------------------------------------------------------

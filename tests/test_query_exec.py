@@ -323,13 +323,36 @@ def test_streaming_aggregator_per_shard_pushes(rng):
 def test_pallas_engine_sharded_parity(rng):
     """Kernel backends keep their per-shard kernels: the tiled groupagg
     kernel runs per shard (its output *is* the partial state for
-    PARTIAL_OPS) and the tables merge in the same tree."""
+    PARTIAL_OPS) and one more kernel pass over the packed tables merges
+    them."""
     g, k = sorted_stream(rng, 512, 9)
     q = Query(ops=("sum", "max"))
     ref, _ = execute(q, jnp.array(g), jnp.array(k), backend="reference")
     sh, _ = execute(q, jnp.array(g), jnp.array(k), backend="pallas",
                     num_shards=4, tile=128)
     _assert_result_equal(ref, sh)
+
+
+@pytest.mark.parametrize("num_shards,n_valid", [(4, None), (3, None),
+                                                (4, 301), (2, 0)])
+def test_pallas_engine_sharded_kernel_merge(rng, num_shards, n_valid):
+    """The kernel merge stage: groups spanning shard boundaries fold
+    exactly for every KERNEL_STATE_OP (counts summed, not counted), with
+    a padded tail, a shard count that is not a power of two, and an empty
+    stream prefix; its telemetry reports the one merge pass."""
+    g, k = sorted_stream(rng, 384, 5)
+    q = Query(ops=("sum", "count", "min", "max"))
+    nv = None if n_valid is None else jnp.asarray(n_valid, jnp.int32)
+    ref, _ = execute(q, jnp.array(g), jnp.array(k), n_valid=nv,
+                     backend="reference")
+    sh, _ = execute(q, jnp.array(g), jnp.array(k), n_valid=nv,
+                    backend="pallas", num_shards=num_shards, tile=128,
+                    collect_stats=True)
+    _assert_result_equal(ref, sh._replace(stats=None))
+    assert int(sh.stats["combine_rounds"]) == 1
+    assert np.asarray(sh.stats["combine_round_width"]).tolist() == [384]
+    assert np.asarray(sh.stats["combine_round_groups"]).tolist() == [
+        int(ref.num_groups)]
 
 
 # ---------------------------------------------------------------------------
@@ -343,9 +366,9 @@ def test_choose_backend_device_aware(no_env_backend):
         def __init__(self, platform):
             self.platform = platform
 
-    assert registry.choose_backend(q, [_Dev("cpu")]) == "reference"
+    assert registry.choose_backend(q, [_Dev("cpu")])[0] == "reference"
     # an accelerator mesh flips the very same query to the pane kernels
-    assert registry.choose_backend(q, [_Dev("tpu")]) == "pallas-panes"
+    assert registry.choose_backend(q, [_Dev("tpu")])[0] == "pallas-panes"
 
 
 @pytest.fixture

@@ -28,6 +28,9 @@ GROUPAGG_TILES = 2 ** 24 // 1024 + 1
 #: per-group windows: 2^20 tuples, WA=128, 256 groups of 9 pane slots
 PG_WA, PG_CHUNKS, PG_CAP = 128, 2 ** 20 // 128, 256 * 9
 PAPER_OPS = ("min", "max", "sum", "count")
+#: event-time windows: range 2048 / slide 512 over 2^20 tuples spread on
+#: 2^21 time units -> 4096 windows, frames 2048 wide
+TIME_WINDOWS, TIME_WCAP = 4096, 2048
 
 
 @pytest.fixture(scope="module")
@@ -95,6 +98,31 @@ def test_groupagg_kernel_compiles(one_chip, op):
     _compile(one_chip, fn, _i32(1, n), _i32(1, n))
 
 
+@pytest.mark.parametrize("op", PAPER_OPS)
+def test_groupagg_merge_kernel_compiles(one_chip, op):
+    """Sharded ``pallas`` group-by: the merge stage folds the packed shard
+    tables with the op's table-level (partial-state) combiner."""
+    from repro.core.combiners import get_combiner, partial_combiner
+    from repro.kernels.groupagg import kernel as k
+
+    fn = functools.partial(k.groupagg_pallas,
+                           combiner=partial_combiner(get_combiner(op)),
+                           tile=1024, out_dtype=jnp.int32, interpret=False)
+    n = GROUPAGG_TILES * 1024
+    _compile(one_chip, fn, _i32(1, n), _i32(1, n))
+
+
+def test_timeframe_kernel_compiles(one_chip):
+    """Grouped event-time windows (replay): the re-sort kernel over
+    ``[windows, wcap]`` time frames, median and dc."""
+    from repro.kernels.swag import kernel as k
+
+    fn = functools.partial(k.swag_pallas, ops=("median", "distinct_count"),
+                           interpret=False)
+    _compile(one_chip, fn, _i32(TIME_WINDOWS, TIME_WCAP),
+             _i32(TIME_WINDOWS, TIME_WCAP))
+
+
 def test_pergroup_fused_kernel_compiles(one_chip):
     """``pallas-panestore`` partial-fused: VMEM-resident store + partials."""
     from repro.kernels.swag import kernel as k
@@ -125,5 +153,5 @@ def test_twostack_kernel_compiles(one_chip):
         return k.twostack_flip_pallas(kf, vf != 0, kb, vb != 0, PAPER_OPS,
                                       interpret=False)
 
-    region = _i32(4096, 1024)
+    region = _i32(TIME_WINDOWS, TIME_WCAP)
     _compile(one_chip, fn, region, region, region, region)
