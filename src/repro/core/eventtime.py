@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import sorter
+from repro.obs.trace import stage
 
 Array = jax.Array
 
@@ -302,8 +303,9 @@ def reorder_push(spec: ReorderSpec, state: ReorderState, ts: Array,
             t, g, k, lv = x
             return _reorder_cycle(spec, st, t, g, k, lv, release_wm, late_wm)
 
-        state, (ets, egs, eks, evs, lates) = jax.lax.scan(
-            step, state, (ts, groups, keys, live))
+        with stage("reorder"):
+            state, (ets, egs, eks, evs, lates) = jax.lax.scan(
+                step, state, (ts, groups, keys, live))
     else:
         from repro.obs import counters as _c
         counters = _c.ensure(counters, ("reorder_depth_hwm",
@@ -316,11 +318,13 @@ def reorder_push(spec: ReorderSpec, state: ReorderState, ts: Array,
                                           late_wm, counters=cnt)
             return (st, cnt), out
 
-        (state, counters), (ets, egs, eks, evs, lates) = jax.lax.scan(
-            step, (state, counters), (ts, groups, keys, live))
+        with stage("reorder"):
+            (state, counters), (ets, egs, eks, evs, lates) = jax.lax.scan(
+                step, (state, counters), (ts, groups, keys, live))
     gate = drain_wm if drain_wm is not None else release_wm
     release = state.max_ts - spec.max_lateness if gate is None else gate
-    drain, state = _reorder_drain(spec, state, release)
+    with stage("reorder"):
+        drain, state = _reorder_drain(spec, state, release)
     emit = ReorderEmit(
         jnp.concatenate([ets, drain.ts]),
         jnp.concatenate([egs, drain.groups]),

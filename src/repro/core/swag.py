@@ -54,6 +54,7 @@ from repro.core import panestore as _panestore
 from repro.core import segscan, sorter
 from repro.core.combiners import (Combiner, get_combiner,
                                   partial_combiner as _mk_partial_combiner)
+from repro.obs.trace import stage
 
 Array = jax.Array
 
@@ -355,21 +356,40 @@ def swag_median(groups: Array, keys: Array, *, ws: int, wa: int,
                         res.num_groups)
 
 
-def per_group_chunk_scan(spec, state, groups: Array, keys: Array, emit):
+def per_group_chunk_scan(spec, state, groups: Array, keys: Array, emit,
+                         counters=None):
     """Thread a pane store over WA-sized stream chunks: push each chunk,
     then apply ``emit`` to the updated store (one evaluation per chunk).
     The trailing remainder (< WA tuples) stays unpushed — mirror of
-    :func:`frame_panes`.  Returns ``(final_state, stacked emissions)``."""
+    :func:`frame_panes`.  Returns ``(final_state, stacked emissions)``;
+    with ``counters`` (an :mod:`repro.obs.counters` dict) ``(final_state,
+    stacked emissions, counters)``, the store's evictions and occupancy
+    high-water mark over every tuple."""
     ne = groups.shape[-1] // spec.wa
-    gc = frame_panes(groups.astype(jnp.int32), spec.wa, ne)
-    kc = frame_panes(keys, spec.wa, ne)
+    with stage("frame"):
+        gc = frame_panes(groups.astype(jnp.int32), spec.wa, ne)
+        kc = frame_panes(keys, spec.wa, ne)
 
-    def step(st, x):
+    if counters is not None:
+        from repro.obs import counters as _c
+        counters = _c.ensure(counters,
+                             ("pane_evictions", "pane_occupancy_hwm"))
+
+    def step(carry, x):
+        st, cnt = carry
         g, k = x
-        st = _panestore.push(spec, st, g, k)
-        return st, emit(st)
+        if cnt is None:
+            st = _panestore.push(spec, st, g, k)
+        else:
+            st, cnt = _panestore.push(spec, st, g, k, counters=cnt)
+        return (st, cnt), emit(st)
 
-    return jax.lax.scan(step, state, (gc, kc))
+    with stage("store_push"):
+        (state, counters), out = jax.lax.scan(step, (state, counters),
+                                              (gc, kc))
+    if counters is None:
+        return state, out
+    return state, out, counters
 
 
 def _group_ranks(groups: Array):
@@ -407,8 +427,9 @@ def _pergroup_dir_scan(spec, gc: Array, rc: Array, with_counters: bool):
     reference scan by construction.
 
     Returns ``(carry, (owner, abase, count) snapshots [NE, C])`` where
-    ``carry`` is ``(owner, count, base, abase, stamp, clock[, evictions])``
-    (the eviction counter rides only when ``with_counters``)."""
+    ``carry`` is ``(owner, count, base, abase, stamp, clock[, evictions,
+    occupancy high-water mark])`` (the counters ride only when
+    ``with_counters``)."""
     c = spec.capacity
     init = (jnp.full((c,), _panestore.PAD_GROUP, jnp.int32),   # owner
             jnp.zeros((c,), jnp.int32),                        # count
@@ -417,7 +438,8 @@ def _pergroup_dir_scan(spec, gc: Array, rc: Array, with_counters: bool):
             jnp.full((c,), -1, jnp.int32),                     # stamp
             jnp.zeros((), jnp.int32))                          # clock
     if with_counters:
-        init = init + (jnp.zeros((), jnp.int32),)              # evictions
+        init = init + (jnp.zeros((), jnp.int32),               # evictions
+                       jnp.zeros((), jnp.int32))               # occupancy
 
     def tup(carry, x):
         owner, count, base, abase, stamp, clock = carry[:6]
@@ -428,7 +450,7 @@ def _pergroup_dir_scan(spec, gc: Array, rc: Array, with_counters: bool):
         abase = abase.at[slot].set(jnp.where(alloc, r, abase[slot]))
         out = (owner, count, base, abase, stamp, clock)
         if with_counters:
-            out = out + (carry[6] + evicted.astype(jnp.int32),)
+            out = out + _count_push(carry[6:], owner, evicted)
         return out, None
 
     def chunk(carry, x):
@@ -436,6 +458,14 @@ def _pergroup_dir_scan(spec, gc: Array, rc: Array, with_counters: bool):
         return carry, (carry[0], carry[3], carry[1])
 
     return jax.lax.scan(chunk, init, (gc, rc))
+
+
+def _count_push(counts, owner: Array, evicted: Array):
+    """``(evictions, occupancy high-water mark)`` after one tuple's push,
+    from the directory's new ``owner`` column."""
+    ev, hwm = counts
+    occupied = jnp.sum((owner != _panestore.PAD_GROUP).astype(jnp.int32))
+    return ev + evicted.astype(jnp.int32), jnp.maximum(hwm, occupied)
 
 
 def _snapshot_directory(own_s: Array):
@@ -592,7 +622,7 @@ def _reconstruct_store(spec, carry, sg: Array, sk: Array):
                                      stamp, clock)
 
 
-def pergroup_write_plan(spec, groups: Array):
+def pergroup_write_plan(spec, groups: Array, counters=None):
     """Everything the fused Pallas replay kernel needs, precomputed by one
     XLA directory scan ("store bookkeeping in XLA", as with the gather
     path): per-tuple write coordinates into the VMEM-resident ring
@@ -602,41 +632,58 @@ def pergroup_write_plan(spec, groups: Array):
     Returns ``(slots, lanes, seqs [NE, WA]; own_s, cnt_s, lo_s, sortmask
     [NE, C]; ugroups [NE, C], num [NE])`` — seq/lo in store-seq units (the
     kernel masks within one epoch; freed slots are masked by ``own_s``).
+    With ``counters`` (an :mod:`repro.obs.counters` dict) returns ``(that
+    tuple, counters)``: the store's evictions and occupancy high-water
+    mark over every tuple.
     """
     ne = groups.shape[-1] // spec.wa
     c = spec.capacity
     pad = _panestore.PAD_GROUP
     imin = jnp.iinfo(jnp.int32).min
-    gc = frame_panes(jnp.asarray(groups, jnp.int32), spec.wa, ne)
+    with stage("frame"):
+        gc = frame_panes(jnp.asarray(groups, jnp.int32), spec.wa, ne)
 
     init = (jnp.full((c,), pad, jnp.int32), jnp.zeros((c,), jnp.int32),
             jnp.zeros((c,), jnp.int32), jnp.full((c,), -1, jnp.int32),
             jnp.zeros((), jnp.int32))
+    if counters is not None:
+        init = init + (jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
 
     def tup(carry, g):
-        carry, slot, lane, m_g, _alloc, _closes, _ev = \
-            _panestore._push_decide(spec, *carry, g, True)
-        return carry, (slot, lane, m_g)
+        directory, slot, lane, m_g, _alloc, _closes, evicted = \
+            _panestore._push_decide(spec, *carry[:5], g, True)
+        if counters is not None:
+            directory = directory + _count_push(carry[5:], directory[0],
+                                                evicted)
+        return directory, (slot, lane, m_g)
 
     def chunk(carry, g):
         carry, (slot, lane, seq) = jax.lax.scan(tup, carry, g)
-        owner, count, base, _stamp, _clock = carry
+        owner, count, base = carry[:3]
         return carry, (slot, lane, seq, owner, count, base)
 
-    _carry, (slots, lanes, seqs, own_s, cnt_s, base_s) = \
-        jax.lax.scan(chunk, init, gc)
+    with stage("dir_scan"):
+        carry, (slots, lanes, seqs, own_s, cnt_s, base_s) = \
+            jax.lax.scan(chunk, init, gc)
 
-    written = jnp.any(
-        slots[:, :, None] == jnp.arange(c)[None, None, :], axis=1)
-    sortmask = (cnt_s == spec.wa) & written
-    occ = own_s != pad
-    span = jnp.where(occ, base_s + cnt_s, imin)
-    samem = (occ[:, :, None] & (own_s[:, :, None] == own_s[:, None, :])
-             & occ[:, None, :])
-    m = jnp.max(jnp.where(samem, span[:, None, :], imin), axis=2)
-    lo_s = jnp.where(occ, m - spec.ws_of(own_s), 0)
-    ugroups, num = _snapshot_directory(own_s)
-    return slots, lanes, seqs, own_s, cnt_s, lo_s, sortmask, ugroups, num
+    with stage("dir_snapshot"):
+        written = jnp.any(
+            slots[:, :, None] == jnp.arange(c)[None, None, :], axis=1)
+        sortmask = (cnt_s == spec.wa) & written
+        occ = own_s != pad
+        span = jnp.where(occ, base_s + cnt_s, imin)
+        samem = (occ[:, :, None] & (own_s[:, :, None] == own_s[:, None, :])
+                 & occ[:, None, :])
+        m = jnp.max(jnp.where(samem, span[:, None, :], imin), axis=2)
+        lo_s = jnp.where(occ, m - spec.ws_of(own_s), 0)
+        ugroups, num = _snapshot_directory(own_s)
+    out = (slots, lanes, seqs, own_s, cnt_s, lo_s, sortmask, ugroups, num)
+    if counters is None:
+        return out
+    from repro.obs import counters as _c
+    counters = _c.bump(counters, "pane_evictions", carry[5])
+    counters = _c.high_water(counters, "pane_occupancy_hwm", carry[6])
+    return out, counters
 
 
 def swag_per_group(groups: Array, keys: Array, *, spec, ops,
@@ -685,21 +732,6 @@ def swag_per_group(groups: Array, keys: Array, *, spec, ops,
             else _panestore.partial_path_names(names, keys.dtype))
     all_partial = bool(psel) and all(psel)
 
-    if counters is not None:
-        from repro.obs import counters as _c
-        counters = _c.put(counters, "pergroup_evals_batched",
-                          jnp.asarray(ne, jnp.int32))
-        counters = _c.put(counters, "pergroup_replay_rows_per_launch",
-                          jnp.asarray(ne * spec.capacity, jnp.int32))
-        counters = _c.put(
-            counters, "pergroup_partial_dispatch",
-            jnp.asarray(len(names) if (all_partial and state is None) else 0,
-                        jnp.int32))
-        counters = _c.put(
-            counters, "pergroup_merge_dispatch",
-            jnp.asarray(0 if (all_partial and state is None) else len(names),
-                        jnp.int32))
-
     if all_partial and state is None and ne > 0:
         ranks, order, sg = _group_ranks(groups)
         sk = keys[order]
@@ -719,34 +751,18 @@ def swag_per_group(groups: Array, keys: Array, *, spec, ops,
             return out, final
         from repro.obs import counters as _c
         counters = _c.bump(counters, "pane_evictions", carry[6])
-        counters = _c.ensure(counters, ("pane_occupancy_hwm",))
+        counters = _c.high_water(counters, "pane_occupancy_hwm", carry[7])
         return out, final, counters
 
     if state is None:
         state = _panestore.init_store(spec, keys.dtype)
-    gc = frame_panes(groups, spec.wa, ne)
-    kc = frame_panes(keys.astype(state.keys.dtype), spec.wa, ne)
-
+    scanned = per_group_chunk_scan(
+        spec, state, groups, keys.astype(state.keys.dtype),
+        lambda st: _panestore.gather_runs(spec, st), counters=counters)
     if counters is None:
-        def step(st, x):
-            g, k = x
-            st = _panestore.push(spec, st, g, k)
-            return st, _panestore.gather_runs(spec, st)
-
-        state, runs = jax.lax.scan(step, state, (gc, kc))
+        state, runs = scanned
     else:
-        from repro.obs import counters as _c
-        counters = _c.ensure(counters,
-                             ("pane_evictions", "pane_occupancy_hwm"))
-
-        def step_c(carry, x):
-            st, cnt = carry
-            g, k = x
-            st, cnt = _panestore.push(spec, st, g, k, counters=cnt)
-            return (st, cnt), _panestore.gather_runs(spec, st)
-
-        (state, counters), runs = jax.lax.scan(step_c, (state, counters),
-                                               (gc, kc))
+        state, runs, counters = scanned
 
     c = spec.capacity
     length = runs.run_keys.shape[-1]

@@ -301,13 +301,12 @@ def _engine_sharded(q, groups, keys, n_valid, *, num_shards, mesh, backend,
 
     n = groups.shape[-1]
     groups = groups.astype(jnp.int32)
-    with _trace.span("partition") as sp:
+    with _trace.span("partition"):
         if n_valid is not None:
             # mask the tail up front so every shard slice keeps the engine's
             # sorted-with-PAD-tail contract locally
             groups = jnp.where(jnp.arange(n) < n_valid, groups, PAD_GROUP)
         gs, ks = partition_stream(groups, keys, num_shards)
-        sp.attach((gs, ks))
     length = n // num_shards
     nvs = None
     if n_valid is not None:
@@ -316,12 +315,11 @@ def _engine_sharded(q, groups, keys, n_valid, *, num_shards, mesh, backend,
     values: dict = {}
     shared = None
     if combiner_ops:
-        with _trace.span("local") as sp:
+        with _trace.span("local"):
             tables = _local_engine_tables(q, gs, ks, nvs, combiner_ops, mesh,
                                           backend, tile=tile,
                                           interpret=interpret)
-            sp.attach(tables)
-        with _trace.span("merge") as sp:
+        with _trace.span("merge"):
             if backend == "pallas":
                 table = _kernel_merge_tables(tables, tile=tile,
                                              interpret=interpret)
@@ -340,11 +338,9 @@ def _engine_sharded(q, groups, keys, n_valid, *, num_shards, mesh, backend,
             # stream; trim so every column matches the single-device layout
             # (real groups never exceed the stream length)
             table = _trim_table(table, n)
-            sp.attach(table)
-        with _trace.span("finalize") as sp:
+        with _trace.span("finalize"):
             g_out, vals, valid, num = _engine.finalize_partial_table(
                 table, combiner_ops)
-            sp.attach((g_out, vals))
         values.update(vals)
         shared = (g_out, valid, num)
 
@@ -352,12 +348,11 @@ def _engine_sharded(q, groups, keys, n_valid, *, num_shards, mesh, backend,
         # run channel: the shard slices are adjacent ranges of the globally
         # (group, key)-sorted stream, so their bitonic merge reproduces the
         # exact input stream the single-device rank pick reads
-        with _trace.span("merge:runs") as sp:
+        with _trace.span("merge", channel="runs"):
             mg, mk = merge_sorted_runs(*_pad_pow2_shards(gs, ks))
             mg, mk = mg[:n], mk[:n]
             t = _swag._median_sorted_window(mg, mk, interpolate=q.interpolate,
                                             n_valid=n_valid)
-            sp.attach(t)
         values["median"] = jnp.where(t.valid, t.medians,
                                      jnp.zeros((), t.medians.dtype))
         shared = shared or (t.groups, t.valid, t.num_groups)

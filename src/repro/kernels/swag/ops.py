@@ -29,6 +29,7 @@ from repro.core.panestore import _key_sentinel
 from repro.core.swag import frame_panes, frame_windows, num_windows, \
     resolve_panes
 from repro.kernels import common as _common
+from repro.obs.trace import stage
 
 
 class SwagResult(NamedTuple):
@@ -72,17 +73,23 @@ def _swag_kernel_exec(groups, keys, *, ws: int, wa: int, ops,
     if panes and wa < ws:
         p = ws // wa
         np_ = nw + p - 1
-        pg = frame_panes(groups.astype(jnp.int32), wa, np_)
-        pk = frame_panes(keys, wa, np_)
-        pg, pk = _k.sort_panes_pallas(pg, pk, interpret=interpret)
-        og, ovs, oc = _k.swag_pallas_panes(pg, pk, ops, p=p,
-                                           interpret=interpret)
+        with stage("frame"):
+            pg = frame_panes(groups.astype(jnp.int32), wa, np_)
+            pk = frame_panes(keys, wa, np_)
+        with stage("sort_panes"):
+            pg, pk = _k.sort_panes_pallas(pg, pk, interpret=interpret)
+        with stage("pane_merge"):
+            og, ovs, oc = _k.swag_pallas_panes(pg, pk, ops, p=p,
+                                               interpret=interpret)
     else:
-        fg = frame_windows(groups.astype(jnp.int32), ws, wa)
-        fk = frame_windows(keys, ws, wa)
-        og, ovs, oc = _k.swag_pallas(fg, fk, ops, interpret=interpret)
-    valid = jnp.arange(ws)[None, :] < oc[:, None]
-    og = jnp.where(valid, og, PAD_GROUP)
+        with stage("frame"):
+            fg = frame_windows(groups.astype(jnp.int32), ws, wa)
+            fk = frame_windows(keys, ws, wa)
+        with stage("window_sort"):
+            og, ovs, oc = _k.swag_pallas(fg, fk, ops, interpret=interpret)
+    with stage("assemble"):
+        valid = jnp.arange(ws)[None, :] < oc[:, None]
+        og = jnp.where(valid, og, PAD_GROUP)
     return og, ovs, valid, oc
 
 
@@ -118,7 +125,8 @@ def _timeframe_kernel_exec(frames_g, frames_k, *, ops,
 
 @functools.partial(jax.jit, static_argnames=("spec", "ops", "interpret"))
 def _swag_pergroup_kernel_exec(groups, keys, *, spec, ops,
-                               interpret: bool | None = None):
+                               interpret: bool | None = None,
+                               counters=None):
     """Per-group-window SWAG with the replay offloaded to Pallas.  The
     store *placement* bookkeeping always runs in XLA; the kernel side has
     two regimes, routed by :func:`repro.core.panestore.partial_path_names`:
@@ -136,7 +144,9 @@ def _swag_pergroup_kernel_exec(groups, keys, *, spec, ops,
 
     ``spec`` is a static :class:`repro.core.panestore.PaneStoreSpec`;
     ``ops`` a tuple of DIRECT_OPS names.  Returns
-    ``(og [NE, C], {name: ov}, valid [NE, C], num_groups [NE])``.
+    ``(og [NE, C], {name: ov}, valid [NE, C], num_groups [NE])``, and with
+    ``counters`` (an :mod:`repro.obs.counters` dict) the counters after
+    them: the store's evictions and occupancy high-water mark.
     """
     from repro.core import panestore as _ps
     from repro.core.swag import per_group_chunk_scan, pergroup_write_plan
@@ -147,42 +157,58 @@ def _swag_pergroup_kernel_exec(groups, keys, *, spec, ops,
     ne = groups.shape[-1] // spec.wa
     c = spec.capacity
     if ne == 0:
-        return (jnp.full((0, c), PAD_GROUP, jnp.int32),
-                {name: jnp.zeros((0, c), _k._pergroup_out_dtype(
-                    name, keys.dtype)) for name in names},
-                jnp.zeros((0, c), bool), jnp.zeros((0,), jnp.int32))
+        out = (jnp.full((0, c), PAD_GROUP, jnp.int32),
+               {name: jnp.zeros((0, c), _k._pergroup_out_dtype(
+                   name, keys.dtype)) for name in names},
+               jnp.zeros((0, c), bool), jnp.zeros((0,), jnp.int32))
+        if counters is None:
+            return out
+        from repro.obs import counters as _c
+        return out + (_c.ensure(counters, ("pane_evictions",
+                                           "pane_occupancy_hwm")),)
 
     psel = _ps.partial_path_names(names, keys.dtype)
     if psel and all(psel):
-        slots, lanes, seqs, own_s, cnt_s, lo_s, sortmask, ugroups, num = \
-            pergroup_write_plan(spec, groups)
-        ck = frame_panes(keys, spec.wa, ne)
-        parts = _k.pergroup_slot_partials_pallas(
-            ck, slots, lanes, seqs, own_s, cnt_s, lo_s, sortmask, names,
-            interpret=interpret)
-        ovs = _combine_slot_partials(own_s, ugroups, parts, names,
-                                     keys.dtype)
+        wp = pergroup_write_plan(spec, groups, counters=counters)
+        if counters is not None:
+            wp, counters = wp
+        slots, lanes, seqs, own_s, cnt_s, lo_s, sortmask, ugroups, num = wp
+        with stage("frame"):
+            ck = frame_panes(keys, spec.wa, ne)
+        with stage("slot_partials"):
+            parts = _k.pergroup_slot_partials_pallas(
+                ck, slots, lanes, seqs, own_s, cnt_s, lo_s, sortmask, names,
+                interpret=interpret)
+        with stage("slot_fold"):
+            ovs = _combine_slot_partials(own_s, ugroups, parts, names,
+                                         keys.dtype)
+        groups_out = ugroups
+    else:
+        state = _ps.init_store(spec, keys.dtype)
+        scanned = per_group_chunk_scan(
+            spec, state, groups, keys, lambda st: _ps.gather_runs(spec, st),
+            counters=counters)
+        if counters is None:
+            _state, runs = scanned
+        else:
+            _state, runs, counters = scanned
+        length = runs.run_keys.shape[-1]
+        with stage("replay"):
+            ovs = _k.pergroup_replay_pallas(
+                runs.run_keys.reshape(ne * c, length),
+                runs.run_valid.reshape(ne * c, length).astype(jnp.int32),
+                names, run=spec.wa, interpret=interpret)
+        groups_out, num = runs.groups, runs.num_groups
+
+    with stage("assemble"):
         valid = jnp.arange(c)[None, :] < num[:, None]
-        values = {name: jnp.where(valid, v, jnp.zeros((), v.dtype))
+        values = {name: jnp.where(valid, v.reshape(ne, c),
+                                  jnp.zeros((), v.dtype))
                   for name, v in ovs.items()}
-        og = jnp.where(valid, ugroups, PAD_GROUP)
+        og = jnp.where(valid, groups_out, PAD_GROUP)
+    if counters is None:
         return og, values, valid, num
-
-    state = _ps.init_store(spec, keys.dtype)
-    state, runs = per_group_chunk_scan(
-        spec, state, groups, keys, lambda st: _ps.gather_runs(spec, st))
-
-    length = runs.run_keys.shape[-1]
-    ovs = _k.pergroup_replay_pallas(
-        runs.run_keys.reshape(ne * c, length),
-        runs.run_valid.reshape(ne * c, length).astype(jnp.int32),
-        names, run=spec.wa, interpret=interpret)
-    valid = jnp.arange(c)[None, :] < runs.num_groups[:, None]
-    values = {name: jnp.where(valid, v.reshape(ne, c),
-                              jnp.zeros((), v.dtype))
-              for name, v in ovs.items()}
-    og = jnp.where(valid, runs.groups, PAD_GROUP)
-    return og, values, valid, runs.num_groups
+    return og, values, valid, num, counters
 
 
 def _combine_slot_partials(own_s, ugroups, parts, names, key_dtype):

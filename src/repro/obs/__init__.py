@@ -5,9 +5,11 @@ exporters.
   streaming carries, the pane store, and the shard combine tree; surfaced
   as ``AggResult.stats`` / ``StreamResult.stats`` via
   ``execute(..., collect_stats=True)``.
-- :mod:`repro.obs.trace` — host-side nested span timers
-  (``with trace.capture() as tr: ...``) around plan / partition / local /
-  merge / finalize / dispatch.
+- :mod:`repro.obs.trace` — device stage scopes (``trace.stage(name)``,
+  named in the compiled program's ``op_name`` metadata) and host spans on
+  the profiler's clock around plan / partition / local / merge / finalize
+  / dispatch (``with trace.capture() as tr: ...`` records their host
+  time).
 - :mod:`repro.obs.registry` — process-wide per-(backend, plan fingerprint)
   observed tuples/s, the measured-cost routing table.
 - :mod:`repro.obs.export` — JSONL and Prometheus text exporters.

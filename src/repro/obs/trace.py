@@ -1,34 +1,78 @@
-"""Host-side nested span tracing for engine stages.
+"""Stage scopes and host spans, both on the profiler's clock.
 
-Usage::
+Device stages.  ``stage(name)`` is a ``jax.named_scope("repro.<name>")``
+around one stage of a jitted engine path::
 
-    from repro.obs import trace
+    with trace.stage("dir_scan"):
+        carry, snaps = jax.lax.scan(chunk, init, gc)
 
-    with trace.capture() as tr:
-        res = execute(q, groups)
-    print(tr.report())
+The scope lands in the ``op_name`` metadata of every HLO instruction the
+stage lowers to, so a profiler trace's device ops map back to stages by
+instruction (the innermost ``repro.<stage>`` segment wins).  It is
+metadata only: the compiled program, stripped of metadata, is the same
+with or without it.  :data:`STAGES` is the one vocabulary; an unknown name
+raises.
 
-Inside the engine, stages are wrapped as::
+Host spans.  ``span(name, **args)`` is a
+``jax.profiler.TraceAnnotation("repro.<name>", **args)`` around host work
+(``plan``, ``dispatch``, the sharded ``partition``/``local``/``merge``/
+``finalize``), visible in a profiler trace beside the device's ops::
 
-    with trace.span("merge") as sp:
-        table = combine_tree(...)
-        sp.attach(table)
+    with trace.span("dispatch", backend=p.backend, path=p.path):
+        res = run(...)
 
-``span()`` is free when no capture is active: it returns a shared no-op
-context manager, so the engine pays one function call and nothing else.
-When a capture *is* active, ``attach()``-ed device values are passed to
-``jax.block_until_ready`` at span exit so the recorded wall time covers
-the actual device work, not just async dispatch.  Tracer values are
-skipped — spans inside a ``jax.jit`` trace record trace time only.
+Inside a :func:`capture` block each span also records its host duration.
+Nothing syncs the device: a span around asynchronous dispatch measures the
+dispatch; device time per stage comes from the profiler's trace.  Under
+``jax.jit`` the spans run at trace time only.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
 from contextlib import contextmanager
-from typing import Any, Iterator, List, Optional
+from typing import Iterator, List, Optional
 
 import jax
+
+PREFIX = "repro."
+
+#: stage name -> (what it covers, the benchmark metric that reads it)
+STAGES = {
+    "frame": ("the stream cut into WA-wide panes (frame_panes)", None),
+    "sort_panes": ("each pane sorted once, _sort_panes_kernel",
+                   "sort_ms_per_push"),
+    "pane_merge": ("each window merged from its presorted panes, then the "
+                   "op tails, _pane_kernel", "pane_merge_ms_per_push"),
+    "window_sort": ("each window re-sorted whole, then the op tails "
+                    "(swag_pallas)", None),
+    "dir_scan": ("the per-tuple pane-store directory scan of "
+                 "pergroup_write_plan", "dir_scan_ms_per_push.per_group"),
+    "dir_snapshot": ("per-chunk directory snapshots after the scan: written "
+                     "slots, close-sort mask, staleness bounds, unique "
+                     "groups", None),
+    "slot_partials": ("per-slot partial aggregates, _pergroup_fused_kernel",
+                      None),
+    "slot_fold": ("slot partials folded into per-group values "
+                  "(_combine_slot_partials)",
+                  "slot_fold_ms_per_push.per_group"),
+    "store_push": ("merge-replay path: pane-store push and run gather per "
+                   "chunk (per_group_chunk_scan)", None),
+    "replay": ("merge-replay path: merge and op tails over the gathered "
+               "runs", None),
+    "reorder": ("the event-time reorder buffer: the scan of "
+                "_reorder_cycle over a push, then the drain", None),
+    "assemble": ("valid mask and padded outputs", None),
+}
+
+
+def stage(name: str):
+    """``jax.named_scope`` of the engine stage ``name`` (from
+    :data:`STAGES`)."""
+    if name not in STAGES:
+        raise ValueError(f"unknown stage {name!r}; stages are "
+                         f"{sorted(STAGES)}")
+    return jax.named_scope(PREFIX + name)
 
 
 @dataclasses.dataclass
@@ -37,10 +81,16 @@ class Span:
     depth: int
     start_s: float
     duration_s: float = 0.0
+    args: dict = dataclasses.field(default_factory=dict)
+
+    def label(self) -> str:
+        extra = ",".join(f"{k}={v}" for k, v in self.args.items())
+        return f"{self.name}[{extra}]" if extra else self.name
 
     def to_dict(self) -> dict:
         return {"name": self.name, "depth": self.depth,
-                "start_s": self.start_s, "duration_s": self.duration_s}
+                "start_s": self.start_s, "duration_s": self.duration_s,
+                "args": dict(self.args)}
 
 
 class Tracer:
@@ -51,10 +101,8 @@ class Tracer:
         self._depth = 0
 
     def report(self) -> str:
-        lines = []
-        for s in self.spans:
-            lines.append(f"{'  ' * s.depth}{s.name}: {s.duration_s * 1e3:.3f} ms")
-        return "\n".join(lines)
+        return "\n".join(f"{'  ' * s.depth}{s.label()}: "
+                         f"{s.duration_s * 1e3:.3f} ms" for s in self.spans)
 
     def to_dicts(self) -> list:
         return [s.to_dict() for s in self.spans]
@@ -81,62 +129,36 @@ def capture() -> Iterator[Tracer]:
         _ACTIVE.remove(tracer)
 
 
-class _NullSpan:
-    __slots__ = ()
-
-    def attach(self, value: Any) -> Any:
-        return value
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        return False
-
-
-_NULL = _NullSpan()
-
-
 class _LiveSpan:
-    __slots__ = ("_tracer", "_span", "_payload")
+    __slots__ = ("_tracer", "_span", "_annotation")
 
-    def __init__(self, tracer: Tracer, name: str) -> None:
+    def __init__(self, tracer: Tracer, name: str, args: dict) -> None:
         self._tracer = tracer
-        self._span = Span(name, tracer._depth, 0.0)
-        self._payload: Any = None
-
-    def attach(self, value: Any) -> Any:
-        """Register device values to sync on at exit; returns them unchanged."""
-        self._payload = value
-        return value
+        self._span = Span(name, tracer._depth, 0.0, args=args)
+        self._annotation = jax.profiler.TraceAnnotation(PREFIX + name,
+                                                        **args)
 
     def __enter__(self) -> "_LiveSpan":
+        self._annotation.__enter__()
         self._span.depth = self._tracer._depth
         self._tracer._depth += 1
         self._span.start_s = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
-        if exc[0] is None and self._payload is not None:
-            _block_until_ready(self._payload)
         self._span.duration_s = time.perf_counter() - self._span.start_s
         self._tracer._depth -= 1
         self._tracer.spans.append(self._span)
+        self._annotation.__exit__(*exc)
         return False
 
 
-def span(name: str):
-    """A context manager timing one engine stage under the active tracer."""
+def span(name: str, **args):
+    """A host span ``repro.<name>`` on the profiler's clock, recorded by
+    the active tracer if there is one."""
     if not _ACTIVE:
-        return _NULL
-    return _LiveSpan(_ACTIVE[-1], name)
-
-
-def _block_until_ready(value: Any) -> None:
-    leaves = [x for x in jax.tree_util.tree_leaves(value)
-              if not isinstance(x, jax.core.Tracer)]
-    if leaves:
-        jax.block_until_ready(leaves)
+        return jax.profiler.TraceAnnotation(PREFIX + name, **args)
+    return _LiveSpan(_ACTIVE[-1], name, args)
 
 
 def active() -> Optional[Tracer]:

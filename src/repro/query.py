@@ -828,29 +828,9 @@ def _execute_window(p: Plan, groups, keys, *, use_xla_sort, interpret,
         spec = w.store_spec()
         if p.backend == "pallas-panestore":
             from repro.kernels.swag.ops import _swag_pergroup_kernel_exec
-            og, ovs, valid, num = _swag_pergroup_kernel_exec(
+            return AggResult(*_swag_pergroup_kernel_exec(
                 groups, keys, spec=spec, ops=q.op_names,
-                interpret=interpret)
-            if counters is not None:
-                from repro.obs import counters as _c
-                names = list(q.op_names)
-                psel = _panestore.partial_path_names(
-                    names, jnp.asarray(keys).dtype)
-                ne = groups.shape[-1] // spec.wa
-                fused = bool(psel) and all(psel)
-                counters = _c.put(counters, "pergroup_evals_batched",
-                                  jnp.asarray(ne, jnp.int32))
-                counters = _c.put(counters,
-                                  "pergroup_replay_rows_per_launch",
-                                  jnp.asarray(ne * spec.capacity, jnp.int32))
-                counters = _c.put(counters, "pergroup_partial_dispatch",
-                                  jnp.asarray(len(names) if fused else 0,
-                                              jnp.int32))
-                counters = _c.put(counters, "pergroup_merge_dispatch",
-                                  jnp.asarray(0 if fused else len(names),
-                                              jnp.int32))
-                return AggResult(og, ovs, valid, num, counters)
-            return AggResult(og, ovs, valid, num)
+                interpret=interpret, counters=counters))
         if counters is not None:
             (og, values, valid, num), _, counters = swag_per_group(
                 groups, keys, spec=spec, ops=q.ops,
@@ -1085,14 +1065,13 @@ def execute(plan_or_query, groups, keys=None, *, state=None, backend=None,
                 "counters live in the threaded carry; pass state=None to "
                 "start a new stream with the other setting")
         step = stream_fn(p, mesh=mesh, collect_stats=collect_stats)
-        with _trace.span(f"dispatch:{p.backend}/stream") as sp:
+        with _trace.span("dispatch", backend=p.backend, path="stream"):
             if is_time:
                 (g, values, valid, num, _rr), new_state = step(
                     groups, keys, state, n_valid, timestamps)
             else:
                 (g, values, valid, num, _rr), new_state = step(
                     groups, keys, state, n_valid)
-            sp.attach((values, new_state))
         stats = dict(new_state[1]) if collect_stats else None
         res = AggResult(g, values, valid, num, stats)
         if collect_stats:
@@ -1104,16 +1083,16 @@ def execute(plan_or_query, groups, keys=None, *, state=None, backend=None,
         counters = {}
 
     if p.num_shards > 1:
-        with _trace.span(f"dispatch:{p.backend}/{p.path}/sharded") as sp:
+        with _trace.span("dispatch", backend=p.backend, path=p.path,
+                         shards=p.num_shards):
             res = _execute_sharded(p, groups, keys, n_valid, mesh=mesh,
                                    use_xla_sort=use_xla_sort,
                                    interpret=interpret, tile=tile,
                                    counters=counters)
-            sp.attach(res)
     elif p.path == "window":
         if n_valid is not None:
             raise ValueError("n_valid applies to non-windowed queries")
-        with _trace.span(f"dispatch:{p.backend}/window") as sp:
+        with _trace.span("dispatch", backend=p.backend, path=p.path):
             if is_time:
                 res = _execute_time_window(p, groups, keys, timestamps,
                                            interpret=interpret)
@@ -1122,12 +1101,10 @@ def execute(plan_or_query, groups, keys=None, *, state=None, backend=None,
                                       use_xla_sort=use_xla_sort,
                                       interpret=interpret,
                                       counters=counters)
-            sp.attach(res)
     else:
-        with _trace.span(f"dispatch:{p.backend}/engine") as sp:
+        with _trace.span("dispatch", backend=p.backend, path=p.path):
             res = _execute_engine(p, groups, keys, n_valid, tile=tile,
                                   interpret=interpret)
-            sp.attach(res)
 
     if collect_stats:
         stats = dict(res.stats) if res.stats else {}

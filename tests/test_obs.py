@@ -231,14 +231,119 @@ def test_trace_capture_nests_dispatch_spans():
     with obs_trace.capture() as tr:
         execute(Query(ops=("sum",)), g, k)
     names = [s.name for s in tr.spans]
-    assert "plan" in names
-    assert any(n.startswith("dispatch:") for n in names)
+    assert names.count("plan") == 1 and names.count("dispatch") == 1
     by_name = {s.name: s for s in tr.spans}
-    dispatch = next(s for s in tr.spans if s.name.startswith("dispatch:"))
+    dispatch = by_name["dispatch"]
+    assert dispatch.args == {"backend": "reference", "path": "engine"}
+    assert dispatch.label() == "dispatch[backend=reference,path=engine]"
     assert by_name["plan"].depth == dispatch.depth
     assert all(s.duration_s >= 0 for s in tr.spans)
-    # no capture active -> span() is the shared no-op
-    assert obs_trace.span("x") is obs_trace.span("y")
+    # no capture active -> a bare profiler annotation, nothing recorded
+    with obs_trace.span("dispatch", backend="x"):
+        pass
+    assert len(tr.spans) == 2
+    assert isinstance(obs_trace.span("plan"), jax.profiler.TraceAnnotation)
+
+
+def test_trace_spans_never_sync_the_device(monkeypatch):
+    """A span measures host time only: nothing inside ``execute`` may wait
+    for the device while a capture is active."""
+    calls = []
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: calls.append(x) or x)
+    g, k = _data(4)
+    with obs_trace.capture() as tr:
+        execute(Query(ops=("sum",), window=Window(ws=32, wa=8)), g, k)
+    assert {s.name for s in tr.spans} == {"plan", "dispatch"}
+    assert calls == []
+
+
+# ---------------------------------------------------------------------------
+# stage scopes: every stage of a path reaches the compiled program
+
+
+def _bench_stages():
+    """``bench/stages.py``, the benchmark's reader of the scopes."""
+    import importlib.util
+    from pathlib import Path
+    path = Path(__file__).resolve().parents[1] / "bench" / "stages.py"
+    spec = importlib.util.spec_from_file_location("bench_stages", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+PAPER_OPS = ("min", "max", "sum", "count")
+PG_WINDOW = Window(ws=64, ws_per_group=64, wa=16, capacity=20)
+
+#: (query, backend, push length, the path's stages); the pushes end in a
+#: partial pane, so framing is a real slice and not a free reshape
+STAGED_PATHS = {
+    "flat-swag": (Query(ops=PAPER_OPS, window=Window(ws=256, wa=64)),
+                  "pallas-panes", 2048 + 32,
+                  {"frame", "sort_panes", "pane_merge", "assemble"}),
+    "resort": (Query(ops=PAPER_OPS, window=Window(ws=256, wa=64)),
+               "pallas", 2048 + 32, {"frame", "window_sort", "assemble"}),
+    "per-group": (Query(ops=PAPER_OPS, window=PG_WINDOW),
+                  "pallas-panestore", 1024 + 8,
+                  {"frame", "dir_scan", "dir_snapshot", "slot_partials",
+                   "slot_fold", "assemble"}),
+    "per-group-merge": (Query(ops=("min", "median"), window=PG_WINDOW),
+                        "pallas-panestore", 1024 + 8,
+                        {"frame", "store_push", "replay", "assemble"}),
+}
+
+
+@pytest.mark.parametrize("path", sorted(STAGED_PATHS))
+def test_compiled_program_carries_every_stage(path):
+    q, backend, n, want = STAGED_PATHS[path]
+    p = plan(q, backend=backend)
+    g = jnp.zeros(n, jnp.int32)
+    text = jax.jit(lambda g, k: execute(p, g, k)[0]).lower(g, g) \
+        .compile().as_text()
+    got = set(_bench_stages().stage_names(text).values())
+    assert got == want
+    assert want <= set(obs_trace.STAGES)
+
+
+def test_reorder_buffer_carries_its_stage():
+    from repro.core import eventtime
+    spec = eventtime.ReorderSpec(capacity=8, max_lateness=4)
+    state = eventtime.init_reorder(spec, jnp.int32)
+    x = jnp.arange(32, dtype=jnp.int32)
+    text = jax.jit(lambda st, t: eventtime.reorder_push(spec, st, t, t, t)) \
+        .lower(state, x).compile().as_text()
+    assert set(_bench_stages().stage_names(text).values()) == {"reorder"}
+
+
+def test_stage_rejects_unknown_name():
+    with pytest.raises(ValueError, match="unknown stage"):
+        obs_trace.stage("dir-scan")
+    with obs_trace.stage("dir_scan"):
+        pass
+
+
+def test_stage_names_from_hlo():
+    """The innermost ``repro.<stage>`` segment of an instruction's
+    ``op_name`` names its stage; an instruction without one is left out
+    (unstaged)."""
+    hlo = """
+HloModule jit_timed
+%body.1 (p: (s32[])) -> (s32[]) {
+  %p = (s32[]) parameter(0), metadata={op_name="jit(f)/repro.dir_scan/while/body/closed_call"}
+  ROOT %fusion.27 = s32[] fusion(%p), kind=kLoop, calls=%fc, metadata={op_name="jit(f)/repro.dir_scan/while/body/repro.frame/add" source_file="x.py" source_line=3}
+}
+ENTRY %main.4 (a: s32[8]) -> s32[8] {
+  %while.78 = (s32[]) while(%t), condition=%c, body=%body.1, metadata={op_name="jit(f)/jit(g)/repro.dir_scan/while"}
+  %repro.sort_panes.1 = s32[8] custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/repro.sort_panes/pallas_call"}
+  %copy_bitcast_fusion = s32[8] fusion(%a), kind=kLoop, calls=%fc2
+  %convert.3 = s32[8] convert(%a), metadata={op_name="jit(f)/jit(_where)/select_n"}
+  ROOT %select.2 = s32[8] select(%a, %a, %a), metadata={op_name="jit(f)/repro.assemble/jit(_where)/select_n"}
+}
+"""
+    assert _bench_stages().stage_names(hlo) == {
+        "p": "dir_scan", "fusion.27": "frame", "while.78": "dir_scan",
+        "repro.sort_panes.1": "sort_panes", "select.2": "assemble"}
 
 
 def test_metrics_registry_accumulates_and_routes():
@@ -383,31 +488,31 @@ def test_choose_backend_consults_metrics():
 # per-group batch-path counters (S2)
 
 
-def test_pergroup_batch_counters_surface():
-    g, k = _data(5, sort_groups=False)
-    w = Window(ws=32, wa=8, ws_per_group={0: 16})
-    cap = w.store_spec().capacity
-    ne = g.shape[0] // 8
-
-    res, _ = execute(Query(ops=("sum", "min"), window=w), g, k,
-                     backend="reference", collect_stats=True)
-    s = res.stats
-    assert int(s["pergroup_evals_batched"]) == ne
-    assert int(s["pergroup_replay_rows_per_launch"]) == ne * cap
-    assert int(s["pergroup_partial_dispatch"]) == 2   # int sum+min
-    assert int(s["pergroup_merge_dispatch"]) == 0
-    assert "pane_evictions" in s
-
-    # any merge op present -> every op rides the merge pass
-    res2, _ = execute(Query(ops=("sum", "median"), window=w), g, k,
-                      backend="reference", collect_stats=True)
-    assert int(res2.stats["pergroup_partial_dispatch"]) == 0
-    assert int(res2.stats["pergroup_merge_dispatch"]) == 2
-
-    # same counters on the kernel backend
-    res3, _ = execute(Query(ops=("sum", "min"), window=w), g, k,
-                      backend="pallas-panestore", collect_stats=True)
-    assert int(res3.stats["pergroup_partial_dispatch"]) == 2
+@pytest.mark.parametrize("capacity", ["exact", "cut"])
+@pytest.mark.parametrize("backend", ["reference", "pallas-panestore"])
+def test_pergroup_batch_counters_surface(backend, capacity):
+    """What an operator of the per-group store watches, on both backends
+    and both of their paths (partial: sum/min, merge-replay: median): no
+    eviction when the store holds every group's window, evictions when it
+    does not, and the occupancy high-water mark within the store."""
+    g, k = _data(5, sort_groups=False)          # 8 groups, 256 tuples
+    ws, wa = 32, 8
+    cap = 8 * (ws // wa + 1) if capacity == "exact" else 12
+    w = Window(ws=ws, wa=wa, ws_per_group=ws, capacity=cap)
+    seen = []
+    for ops in (("sum", "min"), ("sum", "median")):
+        res, _ = execute(Query(ops=ops, window=w), g, k, backend=backend,
+                         collect_stats=True)
+        s = res.stats
+        assert not any(name.startswith("pergroup_") for name in s)
+        seen.append((int(s["pane_evictions"]), int(s["pane_occupancy_hwm"])))
+    evictions, hwm = seen[0]
+    assert seen[1] == seen[0]                   # one placement policy
+    assert 0 < hwm <= cap
+    if capacity == "exact":
+        assert evictions == 0
+    else:
+        assert evictions > 0 and hwm == cap
 
 
 def test_streaming_windowed_dispatch_counters():

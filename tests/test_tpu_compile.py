@@ -155,3 +155,39 @@ def test_twostack_kernel_compiles(one_chip):
 
     region = _i32(TIME_WINDOWS, TIME_WCAP)
     _compile(one_chip, fn, region, region, region, region)
+
+
+@pytest.mark.parametrize("path", ["flat-swag", "per-group"])
+def test_kernels_lie_in_their_stages(one_chip, path):
+    """The benchmark's two paths through ``execute``: every Pallas kernel
+    of the compiled program carries its stage scope, so a profiler trace's
+    kernel time splits by stage (``bench/stages.py`` reads the map)."""
+    import importlib.util
+    import re
+    from pathlib import Path
+
+    from repro.query import Query, Window, execute, plan
+
+    if path == "flat-swag":
+        q, backend, n = (Query(ops=PAPER_OPS, window=Window(ws=WS, wa=WA)),
+                         "pallas-panes", 2 ** 14)
+        want = {"sort_panes", "pane_merge"}
+    else:
+        q, backend, n = (Query(ops=PAPER_OPS, window=Window(
+            ws=1024, ws_per_group=1024, wa=PG_WA, capacity=576)),
+            "pallas-panestore", 2 ** 12)
+        want = {"slot_partials"}
+    p = plan(q, backend=backend)
+    text = _compile(one_chip,
+                    lambda g, k: execute(p, g, k, interpret=False)[0],
+                    _i32(n), _i32(n))
+    spec = importlib.util.spec_from_file_location(
+        "bench_stages", Path(__file__).resolve().parents[1] / "bench"
+        / "stages.py")
+    stages = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(stages)
+    names = stages.stage_names(text)
+    kernels = re.findall(r'^\s*(?:ROOT\s+)?%?([^\s=]+)\s*=.*'
+                         r'custom_call_target="tpu_custom_call"', text,
+                         re.M)
+    assert kernels and {names.get(k) for k in kernels} == want
