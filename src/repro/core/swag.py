@@ -622,37 +622,25 @@ def _reconstruct_store(spec, carry, sg: Array, sk: Array):
                                      stamp, clock)
 
 
-def pergroup_write_plan(spec, groups: Array, counters=None):
-    """Everything the fused Pallas replay kernel needs, precomputed by one
-    XLA directory scan ("store bookkeeping in XLA", as with the gather
-    path): per-tuple write coordinates into the VMEM-resident ring
-    buffers, per-chunk directory snapshots with per-slot staleness bounds,
-    the close-sort mask, and the per-evaluation group directory.
+def _plan_scan(spec, gc: Array, with_counters: bool):
+    """The store-placement plan by one :func:`_push_decide` step per tuple:
+    the per-tuple oracle of :func:`pergroup_write_plan`, which takes it for
+    any push whose panes do not fit the store (the evicting regime).
 
-    Returns ``(slots, lanes, seqs [NE, WA]; own_s, cnt_s, lo_s, sortmask
-    [NE, C]; ugroups [NE, C], num [NE])`` — seq/lo in store-seq units (the
-    kernel masks within one epoch; freed slots are masked by ``own_s``).
-    With ``counters`` (an :mod:`repro.obs.counters` dict) returns ``(that
-    tuple, counters)``: the store's evictions and occupancy high-water
-    mark over every tuple.
-    """
-    ne = groups.shape[-1] // spec.wa
+    Returns ``(slots, lanes, seqs [NE, WA]; own_s, cnt_s, base_s [NE, C])``
+    and, ``with_counters``, the evictions and occupancy high-water mark
+    after them."""
     c = spec.capacity
-    pad = _panestore.PAD_GROUP
-    imin = jnp.iinfo(jnp.int32).min
-    with stage("frame"):
-        gc = frame_panes(jnp.asarray(groups, jnp.int32), spec.wa, ne)
-
-    init = (jnp.full((c,), pad, jnp.int32), jnp.zeros((c,), jnp.int32),
-            jnp.zeros((c,), jnp.int32), jnp.full((c,), -1, jnp.int32),
-            jnp.zeros((), jnp.int32))
-    if counters is not None:
+    init = (jnp.full((c,), _panestore.PAD_GROUP, jnp.int32),
+            jnp.zeros((c,), jnp.int32), jnp.zeros((c,), jnp.int32),
+            jnp.full((c,), -1, jnp.int32), jnp.zeros((), jnp.int32))
+    if with_counters:
         init = init + (jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
 
     def tup(carry, g):
         directory, slot, lane, m_g, _alloc, _closes, evicted = \
             _panestore._push_decide(spec, *carry[:5], g, True)
-        if counters is not None:
+        if with_counters:
             directory = directory + _count_push(carry[5:], directory[0],
                                                 evicted)
         return directory, (slot, lane, m_g)
@@ -662,10 +650,162 @@ def pergroup_write_plan(spec, groups: Array, counters=None):
         owner, count, base = carry[:3]
         return carry, (slot, lane, seq, owner, count, base)
 
-    with stage("dir_scan"):
-        carry, (slots, lanes, seqs, own_s, cnt_s, base_s) = \
-            jax.lax.scan(chunk, init, gc)
+    carry, out = jax.lax.scan(chunk, init, gc)
+    return out + tuple(carry[5:])
 
+
+def _rows_cumulative(op, x: Array) -> Array:
+    """Inclusive running ``op`` (``jnp.maximum`` or ``jnp.add``) over
+    ``x [R, W]`` in row-major order: within rows along the major axis of the
+    transpose, then each row's prefix of the rows before it (both
+    cumulative reductions run down a major axis, which the TPU compiler
+    builds far faster than one down a long minor axis)."""
+    cum = jax.lax.cummax if op is jnp.maximum else jax.lax.cumsum
+    within = cum(x.T, axis=0).T
+    last = within[:, -1]
+    before = jnp.concatenate([jnp.zeros((1,), x.dtype), cum(last)[:-1]])
+    return op(within, before[:, None])
+
+
+def _plan_ranks(spec, gc: Array, with_counters: bool):
+    """Everything of the allocation plan that needs no sequential step.
+
+    A stable group sort gives every tuple its within-group arrival rank
+    ``r``; while no allocation finds the store full, ``r`` is exactly the
+    seq :func:`_push_decide` gives it (no eviction, so no group's count
+    resets, and a group's newest pane never retires on its own push).  So
+    the tuple writes lane ``r % WA`` of its group's pane ``r // WA``, a
+    pane is allocated at its rank-``k WA`` tuple, and it retires at the
+    group's tuple of rank ``k WA + WA + ws_g - 1`` (the first at which
+    ``base + WA <= m - ws_g``), if the push holds that many.
+
+    Per tuple this costs one sort, one scatter and two gathers; the rest
+    is per pane (``A = min(N, NE + C)`` entries, ``count`` of them used:
+    allocations in arrival order).  Returns ``(fits, hwm, rank [NE, WA],
+    pane [N], alloc_t, owner, base, spane, ret [A], count)``: whether every
+    allocation finds a free slot (no eviction, no ``PAD_GROUP`` tuple);
+    the occupancy high-water mark over the push's tuples
+    (``with_counters``, else None); each tuple's rank and its pane's
+    index in group-sorted order; and per allocation its arrival index,
+    group, base, index in group-sorted order and the arrival index of the
+    tuple that retires its pane (``N`` when none does in this push)."""
+    ne, wa = gc.shape
+    n = ne * wa
+    # when the panes fit, allocations <= C + retirements, and a retired
+    # pane is a full one, so retirements <= NE
+    a = min(n, ne + spec.capacity)
+    g = gc.reshape(n)
+    pos = jnp.arange(n, dtype=jnp.int32)
+    sg, order = jax.lax.sort((g, pos), num_keys=1, is_stable=True)
+    first = jnp.concatenate([jnp.ones((1,), bool), sg[1:] != sg[:-1]])
+    seg = _rows_cumulative(jnp.maximum,
+                           jnp.where(first, pos, 0).reshape(ne, wa)).reshape(n)
+    srank = pos - seg
+    spane = _rows_cumulative(jnp.add, ((srank & (wa - 1)) == 0).astype(
+        jnp.int32).reshape(ne, wa)).reshape(n) - 1
+    inv = jnp.zeros((n,), jnp.int32).at[order].set(pos, unique_indices=True)
+    rank = srank[inv]
+
+    alloc = (rank & (wa - 1)) == 0
+    past = rank - (wa + spec.ws_of(g) - 1)
+    retire = (past >= 0) & ((past & (wa - 1)) == 0)
+    na = _rows_cumulative(jnp.add, alloc.astype(jnp.int32).reshape(ne, wa))
+    nr = _rows_cumulative(jnp.add, retire.astype(jnp.int32).reshape(ne, wa))
+    live = (na - nr).reshape(n)
+    # occupancy the moment each allocation is placed: this tuple's
+    # retirement comes after its allocation
+    peak = jnp.max(jnp.where(alloc, live + retire, 0))
+    fits = (peak <= spec.capacity) & ~jnp.any(g == _panestore.PAD_GROUP)
+    hwm = jnp.max(live) if with_counters else None
+
+    alloc_t = jnp.full((a,), n, jnp.int32).at[
+        jnp.where(alloc, na.reshape(n) - 1, a)].set(pos, mode="drop")
+    t = jnp.minimum(alloc_t, n - 1)
+    p = inv[t]
+    q = jnp.minimum(p + wa + spec.ws_of(sg[p]) - 1, n - 1)
+    ret = jnp.where((alloc_t < n) & (p + wa + spec.ws_of(sg[p]) - 1 < n)
+                    & (seg[q] == seg[p]), order[q], n)
+    return (fits, hwm, rank.reshape(ne, wa), spane[inv], alloc_t, sg[p],
+            srank[p], spane[p], ret, na.reshape(n)[-1])
+
+
+def _place_allocations(c: int, alloc_t: Array, alloc_ret: Array,
+                       count: Array) -> Array:
+    """The slot of each of the first ``count`` allocations, in order."""
+    def place(j, carry):
+        release, slot_of = carry
+        s = jnp.argmax(release < alloc_t[j]).astype(jnp.int32)
+        return release.at[s].set(alloc_ret[j]), slot_of.at[j].set(s)
+
+    return jax.lax.fori_loop(
+        0, count, place, (jnp.full((c,), -1, jnp.int32),
+                          jnp.zeros(alloc_t.shape, jnp.int32)))[1]
+
+
+def _plan_by_allocation(spec, rank: Array, pane: Array, alloc_t: Array,
+                        owner: Array, base: Array, spane: Array,
+                        ret: Array, count: Array):
+    """The plan of a push whose panes fit, in one sequential step per pane
+    allocation: each takes the lowest free slot (``_push_decide``'s
+    ``argmax(free)``).  The loop carries, per slot, the arrival index of
+    the tuple that retires its pane, so a slot is free for an allocation
+    once that index lies strictly before it (a tuple allocates before it
+    retires), and no step applies retirements.
+
+    Then, vectorised, every tuple's slot and each chunk's directory
+    snapshot.  A slot's owner, base and retiring tuple after chunk ``e``
+    are those of the last pane placed there by then: a running sum down
+    the chunks of each placement's change from the pane before it in the
+    slot.  The pane is live unless it has retired by then, and its count
+    is the slot's writes so far less ``WA`` for each pane before it (a
+    retired pane is a full one).  Returns what :func:`_plan_scan` does,
+    counters aside."""
+    ne, wa = rank.shape
+    n = ne * wa
+    c = spec.capacity
+    a = alloc_t.shape[0]
+    slot_of = _place_allocations(c, alloc_t, ret, count)
+    j = jnp.arange(a, dtype=jnp.int32)
+    placed = j < count
+    slots = jnp.zeros((a,), jnp.int32).at[jnp.where(placed, spane, a)].set(
+        slot_of, mode="drop")[pane].reshape(ne, wa)
+
+    # the pane placed in the same slot before each one
+    key, jj = jax.lax.sort((jnp.where(placed, slot_of, c), j), num_keys=2)
+    same = jnp.concatenate([jnp.zeros((1,), bool), key[1:] == key[:-1]])
+    before = jnp.zeros((a,), jnp.int32).at[jj].set(
+        jnp.where(same, jnp.roll(jj, 1), a), unique_indices=True)
+    cell = jnp.where(placed, (alloc_t // wa) * c + slot_of, ne * c)
+
+    def down_chunks(x):
+        """Running sum down the chunks of ``x`` added at each placement."""
+        return jax.lax.cumsum(jnp.zeros((ne * c,), x.dtype).at[cell].add(
+            x, mode="drop").reshape(ne, c), axis=0)
+
+    def change(x):
+        return x - jnp.where(before < a, x[jnp.minimum(before, a - 1)], 0)
+
+    panes = down_chunks(jnp.ones((a,), jnp.int32))
+    writes = jax.lax.cumsum(jnp.sum(
+        (slots[:, :, None] == jnp.arange(c)[None, None, :]).astype(
+            jnp.int32), axis=1), axis=0)
+    ends = (jnp.arange(ne, dtype=jnp.int32)[:, None] + 1) * wa
+    live = (panes > 0) & (down_chunks(change(ret)) >= ends)
+    own_s = jnp.where(live, down_chunks(change(owner)),
+                      _panestore.PAD_GROUP)
+    cnt_s = jnp.where(live, writes - wa * (panes - 1), 0)
+    base_s = jnp.where(live, down_chunks(change(base)), 0)
+    return (slots, rank & (wa - 1), rank, own_s, cnt_s, base_s)
+
+
+def _plan_snapshot(spec, slots: Array, lanes: Array, seqs: Array,
+                   own_s: Array, cnt_s: Array, base_s: Array):
+    """The write plan from a directory plan: each chunk's written slots,
+    close-sort mask, per-slot staleness bound and unique groups (see
+    :func:`pergroup_write_plan`)."""
+    c = spec.capacity
+    pad = _panestore.PAD_GROUP
+    imin = jnp.iinfo(jnp.int32).min
     with stage("dir_snapshot"):
         written = jnp.any(
             slots[:, :, None] == jnp.arange(c)[None, None, :], axis=1)
@@ -677,12 +817,61 @@ def pergroup_write_plan(spec, groups: Array, counters=None):
         m = jnp.max(jnp.where(samem, span[:, None, :], imin), axis=2)
         lo_s = jnp.where(occ, m - spec.ws_of(own_s), 0)
         ugroups, num = _snapshot_directory(own_s)
-    out = (slots, lanes, seqs, own_s, cnt_s, lo_s, sortmask, ugroups, num)
+    return (slots, lanes, seqs, own_s, cnt_s, lo_s, sortmask, ugroups, num)
+
+
+def pergroup_write_plan(spec, groups: Array, counters=None):
+    """Everything the fused Pallas replay kernel needs, precomputed in XLA
+    ("store bookkeeping in XLA", as with the gather path): per-tuple write
+    coordinates into the VMEM-resident ring buffers, per-chunk directory
+    snapshots with per-slot staleness bounds, the close-sort mask, and the
+    per-evaluation group directory.
+
+    The directory takes one sequential step per pane allocation
+    (:func:`_plan_ranks`, :func:`_plan_by_allocation`) when every
+    allocation of the push finds a free slot, and otherwise the per-tuple
+    scan (:func:`_plan_scan`), which alone models eviction; both give the
+    same plan wherever the first applies.
+
+    Returns ``(slots, lanes, seqs [NE, WA]; own_s, cnt_s, lo_s, sortmask
+    [NE, C]; ugroups [NE, C], num [NE])`` — seq/lo in store-seq units (the
+    kernel masks within one epoch; freed slots are masked by ``own_s``).
+    With ``counters`` (an :mod:`repro.obs.counters` dict) returns ``(that
+    tuple, counters)``: the store's evictions and occupancy high-water
+    mark over every tuple, and ``pane_dir_fallbacks``, 1 when the push
+    took the per-tuple scan.
+    """
+    ne = groups.shape[-1] // spec.wa
+    stats = counters is not None
+    with stage("frame"):
+        gc = frame_panes(jnp.asarray(groups, jnp.int32), spec.wa, ne)
+
+    with stage("dir_scan"):
+        if spec.is_time or ne == 0:
+            plan = _plan_scan(spec, gc, stats)
+            fell_back = jnp.ones((), jnp.int32)
+        else:
+            fits, hwm, *ranked = _plan_ranks(spec, gc, stats)
+
+            def by_allocation(ranked):
+                out = _plan_by_allocation(spec, *ranked)
+                return out + ((jnp.zeros((), jnp.int32), hwm) if stats
+                              else ())
+
+            plan = jax.lax.cond(fits, by_allocation,
+                                lambda _: _plan_scan(spec, gc, stats),
+                                tuple(ranked))
+            # keep XLA from sinking the snapshot's [NE, C, C] broadcasts
+            # into both branches, where they would be materialised
+            plan = jax.lax.optimization_barrier(plan)
+            fell_back = (~fits).astype(jnp.int32)
+    out = _plan_snapshot(spec, *plan[:6])
     if counters is None:
         return out
     from repro.obs import counters as _c
-    counters = _c.bump(counters, "pane_evictions", carry[5])
-    counters = _c.high_water(counters, "pane_occupancy_hwm", carry[6])
+    counters = _c.bump(counters, "pane_evictions", plan[6])
+    counters = _c.high_water(counters, "pane_occupancy_hwm", plan[7])
+    counters = _c.bump(counters, "pane_dir_fallbacks", fell_back)
     return out, counters
 
 

@@ -146,7 +146,8 @@ def _swag_pergroup_kernel_exec(groups, keys, *, spec, ops,
     ``ops`` a tuple of DIRECT_OPS names.  Returns
     ``(og [NE, C], {name: ov}, valid [NE, C], num_groups [NE])``, and with
     ``counters`` (an :mod:`repro.obs.counters` dict) the counters after
-    them: the store's evictions and occupancy high-water mark.
+    them: the store's evictions and occupancy high-water mark, and on the
+    partial-fused path ``pane_dir_fallbacks``.
     """
     from repro.core import panestore as _ps
     from repro.core.swag import per_group_chunk_scan, pergroup_write_plan
@@ -165,7 +166,8 @@ def _swag_pergroup_kernel_exec(groups, keys, *, spec, ops,
             return out
         from repro.obs import counters as _c
         return out + (_c.ensure(counters, ("pane_evictions",
-                                           "pane_occupancy_hwm")),)
+                                           "pane_occupancy_hwm",
+                                           "pane_dir_fallbacks")),)
 
     psel = _ps.partial_path_names(names, keys.dtype)
     if psel and all(psel):

@@ -20,6 +20,8 @@ Counter names used by the engine:
 =========================  ====================================================
 ``pane_evictions``         occupied pane slots displaced by capacity pressure
 ``pane_occupancy_hwm``     high-water mark of occupied slots in the pane store
+``pane_dir_fallbacks``     pushes whose write plan took the per-tuple directory
+                           scan because their panes did not fit the store
 ``reorder_depth_hwm``      high-water mark of buffered tuples in the reorder ring
 ``reorder_forced_pops``    pops forced by a full ring rather than the watermark
 ``late_dropped``           tuples dropped for violating the lateness contract

@@ -46,8 +46,11 @@ STAGES = {
                    "op tails, _pane_kernel", "pane_merge_ms_per_push"),
     "window_sort": ("each window re-sorted whole, then the op tails "
                     "(swag_pallas)", None),
-    "dir_scan": ("the per-tuple pane-store directory scan of "
-                 "pergroup_write_plan", "dir_scan_ms_per_push.per_group"),
+    "dir_scan": ("the pane-store directory of pergroup_write_plan: ranks "
+                 "by a stable group sort, one loop step per pane "
+                 "allocation, snapshots by slot; the per-tuple scan when "
+                 "the push's panes do not fit",
+                 "dir_scan_ms_per_push.per_group"),
     "dir_snapshot": ("per-chunk directory snapshots after the scan: written "
                      "slots, close-sort mask, staleness bounds, unique "
                      "groups", None),

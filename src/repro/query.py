@@ -738,6 +738,7 @@ def _init_stream_counters(p: Plan) -> dict:
     if w is not None:
         return _c.init(pane_evictions=jnp.zeros((), jnp.int32),
                        pane_occupancy_hwm=jnp.zeros((), jnp.int32),
+                       pane_dir_fallbacks=jnp.zeros((), jnp.int32),
                        pergroup_partial_ops=jnp.zeros((), jnp.int32),
                        pergroup_merge_ops=jnp.zeros((), jnp.int32))
     return _c.init(stream_tuples=jnp.zeros((), jnp.int32),

@@ -494,7 +494,9 @@ def test_pergroup_batch_counters_surface(backend, capacity):
     """What an operator of the per-group store watches, on both backends
     and both of their paths (partial: sum/min, merge-replay: median): no
     eviction when the store holds every group's window, evictions when it
-    does not, and the occupancy high-water mark within the store."""
+    does not, and the occupancy high-water mark within the store; on the
+    kernel backend's partial path, whether the push's directory fell back
+    to the per-tuple scan (exactly when the store evicts)."""
     g, k = _data(5, sort_groups=False)          # 8 groups, 256 tuples
     ws, wa = 32, 8
     cap = 8 * (ws // wa + 1) if capacity == "exact" else 12
@@ -506,6 +508,8 @@ def test_pergroup_batch_counters_surface(backend, capacity):
         s = res.stats
         assert not any(name.startswith("pergroup_") for name in s)
         seen.append((int(s["pane_evictions"]), int(s["pane_occupancy_hwm"])))
+        if backend == "pallas-panestore" and "median" not in ops:
+            assert int(s["pane_dir_fallbacks"]) == (capacity == "cut")
     evictions, hwm = seen[0]
     assert seen[1] == seen[0]                   # one placement policy
     assert 0 < hwm <= cap

@@ -470,3 +470,109 @@ def test_streaming_push_traces_once(rng):
     for lo in range(0, 96, 32):
         agg.push(jnp.array(g[lo:lo + 32]), jnp.array(k[lo:lo + 32]))
     assert agg._step._cache_size() == 1
+
+
+# ---------------------------------------------------------------------------
+# the write plan by pane allocations: bit-exact vs the per-tuple scan
+# ---------------------------------------------------------------------------
+
+PLAN_FIELDS = ("slots", "lanes", "seqs", "own_s", "cnt_s", "lo_s",
+               "sortmask", "ugroups", "num")
+
+
+def _plan_stream(regime, rng):
+    """``(spec, groups)`` of one directory regime, drawn from ``rng``."""
+    wa = int(rng.choice((1, 2, 4, 8)))
+    ne = int(rng.integers(1, 13))
+    per_group = ()
+    if regime == "squeezed":
+        # ws = 1 (mod WA): a pane retires on the tuple that opens another
+        ws = wa * int(rng.integers(0, 3)) + 1
+        ne = max(ne, -(-ws // wa) + 2)
+    else:
+        ws = wa * int(rng.integers(1, 5)) if regime == "exact" else \
+            int(rng.integers(1, 40))
+    n_groups = {"one-group": 1, "ne-1": int(rng.integers(1, 9))}.get(
+        regime, int(rng.integers(2, 7)))
+    if regime == "ne-1":
+        ne = 1
+    if regime == "overrides":
+        # off the pane grid, one past it, and narrower than a pane
+        per_group = ((0, 3 * wa + 1 + int(rng.integers(1, wa + 1))
+                      if wa > 1 else 5),
+                     (1, 2 * wa + 1), (2, max(1, wa // 2)))
+    spec0 = ps.PaneStoreSpec(wa=wa, capacity=64, default_ws=ws,
+                             per_group=per_group)
+    need = sum(-(-spec0.ws_of(jnp.int32(gid)).item() // wa) + 1
+               for gid in range(n_groups))
+    cap = {"ample": need + int(rng.integers(1, 9)), "exact": need,
+           "squeezed": spec0.min_capacity,
+           "more-groups": spec0.min_capacity}.get(regime, need)
+    if regime == "more-groups":
+        n_groups = cap + int(rng.integers(1, 4))
+        ne = max(ne, -(-n_groups // wa))
+    g = rng.integers(0, n_groups, ne * wa + int(rng.integers(0, wa))
+                     ).astype(np.int32)
+    if regime == "more-groups":
+        g[:n_groups] = np.arange(n_groups)
+    if regime == "squeezed":
+        # group 1 holds a slot while group 0 fills the rest: each of group
+        # 0's later allocations overfills the store on the tuple that
+        # retires one of its panes (the fallback test counts allocations
+        # before retirements)
+        g[0] = 1
+        g[1:] = 0
+    spec = ps.PaneStoreSpec(wa=wa, capacity=max(cap, spec0.min_capacity),
+                            default_ws=ws, per_group=per_group)
+    return spec, g
+
+
+def _check_plan_matches_scan(spec, g):
+    """``pergroup_write_plan`` against the per-tuple scan, element by
+    element and dtype by dtype; returns the fallback count."""
+    import jax
+
+    from repro.core.swag import (_plan_scan, _plan_snapshot, frame_panes,
+                                 pergroup_write_plan)
+
+    def scan_plan(g):
+        p = _plan_scan(spec, frame_panes(g, spec.wa, g.shape[-1] // spec.wa),
+                       True)
+        return _plan_snapshot(spec, *p[:6]), p[6], p[7]
+
+    plan, counters = jax.jit(
+        lambda g: pergroup_write_plan(spec, g, counters={}))(g)
+    want, evictions, hwm = jax.jit(scan_plan)(g)
+    for name, got, ref in zip(PLAN_FIELDS, plan, want):
+        assert got.dtype == ref.dtype, name
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref),
+                                      err_msg=name)
+    assert int(counters["pane_evictions"]) == int(evictions)
+    assert int(counters["pane_occupancy_hwm"]) == int(hwm)
+    fallbacks = int(counters["pane_dir_fallbacks"])
+    # the scan is taken exactly when some allocation finds the store full
+    assert fallbacks == int(int(evictions) > 0)
+    return fallbacks
+
+
+@pytest.mark.parametrize("regime", ["ample", "exact", "squeezed",
+                                    "overrides", "one-group", "more-groups",
+                                    "ne-1"])
+@settings(max_examples=5, deadline=None)
+@given(seed=st.integers(0, 2**31 - 1))
+def test_property_write_plan_matches_tuple_scan(regime, seed):
+    """The directory by pane allocations gives the per-tuple scan's plan,
+    all nine outputs and the three counters, in every regime; it falls
+    back to the scan exactly when the push's panes do not fit."""
+    spec, g = _plan_stream(regime, np.random.default_rng(seed))
+    fallbacks = _check_plan_matches_scan(spec, jnp.asarray(g))
+    assert fallbacks == (regime in ("squeezed", "more-groups"))
+
+
+def test_write_plan_benchmark_shape_takes_no_fallback():
+    """The benchmark cell's store, scaled down to 2^14 tuples: 64 uniform
+    groups, WA 128, WS 1024, 576 = 64 x (1024/128 + 1) slots."""
+    rng = np.random.default_rng(14)
+    spec = ps.PaneStoreSpec(wa=128, capacity=576, default_ws=1024)
+    g = rng.integers(0, 64, 2 ** 14).astype(np.int32)
+    assert _check_plan_matches_scan(spec, jnp.asarray(g)) == 0
