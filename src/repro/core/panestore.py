@@ -793,6 +793,60 @@ def _replay_partials(spec: PaneStoreSpec, state: PaneStoreState, names):
     return ugroups, out, valid, num
 
 
+def time_window_ends(spec: PaneStoreSpec, state: PaneStoreState,
+                     next_end: Array, wm: Array, rows: int):
+    """Closed hopping windows of a time-mode store: the ends of the
+    windows, oldest first, that hold a stored tuple, end at or after the
+    cursor ``next_end`` and at or before the watermark ``wm``; at most
+    ``rows`` of them.  Returns ``(ends [rows], emit [rows], cursor)``
+    (``ends`` is TS_FLOOR where ``emit`` is False)."""
+    s, rng = spec.slide, spec.time_range
+    lanes = jnp.arange(spec.wa)[None, :]
+    occ = (state.owner != PAD_GROUP)[:, None] & (lanes < state.count[:, None])
+    ts = state.seqs
+    c = jnp.asarray(next_end, jnp.int32)
+    ends, emit = [], []
+    go = jnp.asarray(True)
+    big = jnp.iinfo(jnp.int32).max
+    for _ in range(rows):
+        # each tuple's first window at or after the cursor, where one holds it
+        e_t = jnp.maximum(c, (jnp.floor_divide(ts, s) + 1) * s)
+        ok = occ & (ts >= c - rng) & (e_t - rng <= ts)
+        e = jnp.min(jnp.where(ok, e_t, big))
+        go = go & (e != big) & (e <= wm)
+        ends.append(jnp.where(go, e, TS_FLOOR))
+        emit.append(go)
+        c = jnp.where(go, e + s, c)
+    return jnp.stack(ends), jnp.stack(emit), c
+
+
+def emit_time_windows(spec: PaneStoreSpec, state: PaneStoreState, ops,
+                      next_end: Array, wm: Array, rows: int, *,
+                      interpolate: bool = False):
+    """Evaluate each closed window of :func:`time_window_ends` with
+    :func:`replay` at its end.  Returns ``((groups [rows, C], values,
+    valid, num [rows], ends [rows]), cursor)``; rows past the emitted
+    windows hold only pad lanes."""
+    ends, go, cursor = time_window_ends(spec, state, next_end, wm, rows)
+
+    def empty():
+        g, values, valid, num = jax.eval_shape(
+            lambda: replay(spec, state, ops, interpolate=interpolate,
+                           eval_time=jnp.zeros((), jnp.int32)))
+        return (jnp.full(g.shape, PAD_GROUP, jnp.int32),
+                {k: jnp.zeros(v.shape, v.dtype) for k, v in values.items()},
+                jnp.zeros(valid.shape, bool), jnp.zeros((), jnp.int32))
+
+    def one(args):
+        e, g = args
+        return jax.lax.cond(
+            g, lambda: replay(spec, state, ops, interpolate=interpolate,
+                              eval_time=e), empty)
+
+    groups, values, valid, num = jax.lax.map(one, (ends, go))
+    return (groups, values, valid, num, ends), cursor
+
+
 def replay(spec: PaneStoreSpec, state: PaneStoreState, ops, *,
            interpolate: bool = False, eval_time: Array | None = None):
     """Evaluate every live group's window from the store (reference path).

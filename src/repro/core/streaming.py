@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import engine as _engine
+from repro.core import panetable as _panetable
 from repro.core import segscan
 from repro.core.combiners import Combiner, get_combiner
 
@@ -46,6 +47,10 @@ class StreamResult(NamedTuple):
     #: anyway); the full counters dict with ``collect_stats=True``; None
     #: otherwise
     stats: Any = None
+    #: event-time windows only: the ``[rows]`` window end of each row (the
+    #: other fields are then ``[rows, lanes]``, one closed window a row;
+    #: ``TS_MIN`` where a row holds none)
+    window_ends: Any = None
 
 
 def stream_push(groups: Array, keys: Array, carries, combiners, *,
@@ -234,6 +239,7 @@ class StreamingAggregator:
         self.num_shards = num_shards or 1
         self.mesh = mesh
         self.collect_stats = bool(collect_stats)
+        self.key_dtype = key_dtype
         self.plan = _q.plan(
             _q.Query(ops=(self.combiner,), window=window, streaming=True),
             backend="reference", num_shards=self.num_shards)
@@ -269,11 +275,43 @@ class StreamingAggregator:
                 self._donated_buffers, jnp.int32)
             return stats
         if self.window is not None and self.window.is_time:
-            rstate = self._base_carry()[0]
-            dropped = (jnp.sum(rstate.dropped) if self.num_shards > 1
-                       else rstate.dropped)
+            carry = self._base_carry()
+            if isinstance(carry, _panetable.TableState):
+                return {"late_dropped": carry.dropped}
+            dropped = (jnp.sum(carry[0].dropped) if self.num_shards > 1
+                       else carry[0].dropped)
             return {"late_dropped": dropped}
         return None
+
+    def _flush_windows(self, carry):
+        """Every window an event-time carry still holds, as one
+        :class:`repro.core.panetable.Emission`."""
+        ops = (self.combiner,)
+        if isinstance(carry, _panetable.TableState):
+            return _panetable.flush(self.window.table_spec(), carry, ops)
+        from repro.core import eventtime as _eventtime
+        from repro.core import panestore as _ps
+        rspec = self.window.reorder_spec()
+        spec = self.window.store_spec()
+        rows = self.window.table_spec().rows
+        rstate, pstate, next_end = carry
+        if self.num_shards > 1:
+            from repro.distributed import query_exec as _qx
+            emits, rstate = jax.vmap(
+                lambda st: _eventtime.reorder_flush(rspec, st))(rstate)
+            eg, ek, ets, elive = _qx.merge_emissions(emits)
+        else:
+            emit, rstate = _eventtime.reorder_flush(rspec, rstate)
+            eg, ek, ets, elive = emit.groups, emit.keys, emit.ts, emit.live
+        pstate = _ps.push_time(spec, pstate, eg, ek, ets, live=elive)
+        end = jnp.asarray(_panetable.TS_END, jnp.int32)
+
+        def emit_once(cursor):
+            out, cursor = _ps.emit_time_windows(spec, pstate, ops, cursor,
+                                                end, rows)
+            return _panetable.Emission(*out), cursor
+
+        return _panetable.drain(emit_once, next_end, rows)
 
     def push(self, groups: Array, keys: Array,
              n_valid: Array | None = None,
@@ -298,8 +336,11 @@ class StreamingAggregator:
             if timestamps is not None:
                 timestamps = jnp.asarray(timestamps).reshape(-1)
         if is_time:
-            (g, values, valid, num, rr), self.carry = self._step(
+            (g, values, valid, num, rr, ends), self.carry = self._step(
                 groups, keys, self.carry, n_valid, timestamps)
+            self._donated_buffers += self._carry_leaves
+            return StreamResult(g, values[self.combiner.name], valid, num,
+                                rr, self._stats(), ends)
         else:
             (g, values, valid, num, rr), self.carry = self._step(
                 groups, keys, self.carry, n_valid)
@@ -309,38 +350,19 @@ class StreamingAggregator:
 
     def flush(self) -> StreamResult:
         """Close the stream: emit the open group (windowed: re-emit every
-        live group's current window; event-time: drain the reorder
-        buffer(s) and evaluate past the last tuple), reset the carry."""
+        live group's current window; event-time: every window left that
+        holds a tuple, as closed-window rows, after draining the reorder
+        buffer(s)), reset the carry."""
         from repro import query as _q
         carry = self._base_carry()
         stats = self._stats()
         if self.window is not None and self.window.is_time:
-            from repro.core import eventtime as _eventtime
-            from repro.core import panestore as _ps
-            rspec = self.window.reorder_spec()
-            spec = self.window.store_spec()
-            rstate, pstate = carry
-            if self.num_shards > 1:
-                from repro.distributed import query_exec as _qx
-                emits, rstate = jax.vmap(
-                    lambda st: _eventtime.reorder_flush(rspec, st))(rstate)
-                eg, ek, ets, elive = _qx.merge_emissions(emits)
-                end = jnp.max(rstate.max_ts)
-            else:
-                emit, rstate = _eventtime.reorder_flush(rspec, rstate)
-                eg, ek, ets, elive = emit.groups, emit.keys, emit.ts, \
-                    emit.live
-                end = rstate.max_ts
-            pstate = _ps.push_time(spec, pstate, eg, ek, ets, live=elive)
-            g, values, valid, num = _ps.replay(
-                spec, pstate, (self.combiner,), eval_time=end + 1)
-            rr = jnp.where(valid, jnp.arange(spec.capacity) % self.p_ports,
-                           -1)
+            g, values, valid, num, rr, ends = _q.time_ports(
+                self._flush_windows(carry), self.p_ports)
             self.carry = _q.init_stream_state(
-                self.plan, pstate.keys.dtype,
-                collect_stats=self.collect_stats)
+                self.plan, self.key_dtype, collect_stats=self.collect_stats)
             return StreamResult(g, values[self.combiner.name], valid, num,
-                                rr, stats)
+                                rr, stats, ends)
         if self.window is not None:
             from repro.core import panestore as _ps
             spec = self.window.store_spec()

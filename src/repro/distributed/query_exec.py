@@ -515,18 +515,21 @@ def stream_push_eventtime_sharded(q, groups, keys, timestamps, state, *,
     The released emissions of all shards are merged into one
     timestamp-ordered batch (``lax.sort`` with the flat lane index as the
     tie-break — deterministic for any shard interleaving) before the store
-    ingest; evaluation replays the window ``[wm - range, wm)`` at the
-    global watermark.  Returns the streaming port tuple + new state,
-    shaped like the single-shard event-time step (plus the counters dict
-    when ``counters`` is given — reorder depth/forced pops reduced over
-    shards, pane-store evictions/occupancy, late drops, watermark lag).
+    ingest; the push then emits the closed hopping windows the global
+    watermark has passed (:func:`repro.core.panestore.emit_time_windows`).
+    Returns the streaming port tuple + new state, shaped like the
+    single-shard reorder route's (plus the counters dict when ``counters``
+    is given — reorder depth/forced pops reduced over shards, pane-store
+    evictions/occupancy, late drops, windows emitted, watermark lag).
     """
+    from repro import query as _q
     from repro.core import eventtime as _et
     from repro.core import panestore as _ps
+    from repro.core import panetable as _pt
     w = q.window
     rspec = w.reorder_spec()
     spec = w.store_spec()
-    rstates, pstate = state
+    rstates, pstate, next_end = state
 
     n = groups.shape[-1]
     groups = groups.astype(jnp.int32)
@@ -584,25 +587,28 @@ def stream_push_eventtime_sharded(q, groups, keys, timestamps, state, *,
     sg, sk, sts, slive = merge_emissions(emits)
     if counters is None:
         pstate = _ps.push_time(spec, pstate, sg, sk, sts, live=slive,
-                               retire_below=global_wm - w.range)
+                               retire_below=next_end - w.range)
     else:
         pstate, counters = _ps.push_time(spec, pstate, sg, sk, sts,
                                          live=slive,
-                                         retire_below=global_wm - w.range,
+                                         retire_below=next_end - w.range,
                                          counters=counters)
+    out, next_end = _ps.emit_time_windows(
+        spec, pstate, q.ops, next_end, global_wm, w.table_spec().rows,
+        interpolate=q.interpolate)
+    ports = _q.time_ports(_pt.Emission(*out), p_ports)
+    if counters is not None:
         counters = _c.put(counters, "late_dropped", jnp.sum(rstates.dropped))
         counters = _c.put(counters, "watermark", global_wm)
         # how far the fastest shard runs ahead of the merged release gate —
         # the skew the min-merge rule is absorbing
         counters = _c.put(counters, "watermark_lag",
                           jnp.max(new_max - w.max_lateness) - global_wm)
-    g, values, valid, num = _ps.replay(spec, pstate, q.ops,
-                                       interpolate=q.interpolate,
-                                       eval_time=global_wm)
-    rr = jnp.where(valid, jnp.arange(spec.capacity) % p_ports, -1)
+        counters = _c.bump(counters, "windows_emitted", jnp.sum(
+            (ports[3] > 0).astype(jnp.int32)))
     if counters is None:
-        return (g, values, valid, num, rr), (rstates, pstate)
-    return (g, values, valid, num, rr), (rstates, pstate), counters
+        return ports, (rstates, pstate, next_end)
+    return ports, (rstates, pstate, next_end), counters
 
 
 def merge_emissions(emits):

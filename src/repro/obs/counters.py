@@ -18,7 +18,8 @@ Conventions
 Counter names used by the engine:
 
 =========================  ====================================================
-``pane_evictions``         occupied pane slots displaced by capacity pressure
+``pane_evictions``         occupied pane slots (pane-partial table: rows)
+                           displaced by capacity pressure
 ``pane_occupancy_hwm``     high-water mark of occupied slots in the pane store
 ``pane_dir_fallbacks``     pushes whose write plan took the per-tuple directory
                            scan because their panes did not fit the store
@@ -26,6 +27,8 @@ Counter names used by the engine:
 ``reorder_forced_pops``    pops forced by a full ring rather than the watermark
 ``late_dropped``           tuples dropped for violating the lateness contract
 ``watermark``              current (min-merged) event-time watermark
+``windows_emitted``        closed event-time windows emitted so far
+``pane_rows_hwm``          high-water mark of rows in the pane-partial table
 ``watermark_lag``          max shard watermark minus the merged global watermark
 ``stream_tuples``          tuples pushed through a streaming carry
 ``stream_emitted``         groups emitted (retired) by streaming pushes

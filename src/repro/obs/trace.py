@@ -66,6 +66,16 @@ STAGES = {
     "reorder": ("the event-time reorder buffer: the scan of "
                 "_reorder_cycle over a push, then the drain", None),
     "assemble": ("valid mask and padded outputs", None),
+    "time_partials": ("the pane-partial table's push: lateness by a "
+                      "cumulative max, pane ids, the sort by (pane, group) "
+                      "and one partial row per run",
+                      "time_partials_ms_per_push"),
+    "table_merge": ("the push's partial rows merged into the sorted "
+                    "pane-partial table by rank, retired panes dropped",
+                    "table_merge_ms_per_push"),
+    "window_emit": ("closed hopping windows: each window's panes merged by "
+                    "group, folded and compacted into a row",
+                    "window_emit_ms_per_push"),
 }
 
 

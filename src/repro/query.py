@@ -54,6 +54,7 @@ import jax.numpy as jnp
 
 from repro.core import engine as _engine
 from repro.core import panestore as _panestore
+from repro.core import panetable as _panetable
 from repro.core import streaming as _streaming
 from repro.core.swag import (_median_sorted_window, _swag, _swag_median,
                              swag_multi, swag_per_group)
@@ -71,6 +72,10 @@ OP_ALIASES = {
     "average": "mean",
     "med": "median",
 }
+
+
+#: rows of the pane-partial table when ``Window(capacity=...)`` is unset
+TABLE_CAPACITY = 4096
 
 
 def canonical_op(name: str) -> str:
@@ -124,6 +129,14 @@ class Window:
     by **watermark advance** (``wm = max_ts - max_lateness``), not tuple
     count; ``wa`` becomes the tuple capacity of one pane slot (power of
     two, default 8) and ``capacity`` the slot count of the shared store.
+    On one device, a streaming query whose every op is a partial-path op
+    (:func:`repro.core.panestore.partial_path_names`) runs on the sorted
+    pane-partial table of :mod:`repro.core.panetable` instead, with
+    ``capacity`` its row count (default 4096) and no reorder buffer.
+    Every streaming route emits **closed hopping windows**: window
+    ``[e - R, e)`` once, by the first push whose watermark is at or past
+    ``e``, if it holds a tuple (at most ``ceil(R / S)`` windows a push;
+    later ones wait in order).
     ``strategy`` picks the batch execution strategy: ``"replay"``
     (re-aggregate each framed window — any op), ``"twostack"`` (the
     flip-batched two-stack of :mod:`repro.core.twostack` — replay-free,
@@ -240,6 +253,17 @@ class Window:
         return _panestore.PaneStoreSpec(wa=self.wa, capacity=cap,
                                         default_ws=default, per_group=pairs)
 
+    def table_spec(self) -> "_panetable.TableSpec":
+        """The pane-partial table this (time) clause implies."""
+        if not self.is_time:
+            raise ValueError("pane-partial tables serve Window(range=...) "
+                             "only")
+        return _panetable.TableSpec(
+            capacity=TABLE_CAPACITY if self.capacity is None
+            else self.capacity,
+            time_range=self.range, slide=self.slide,
+            max_lateness=self.max_lateness)
+
     def reorder_spec(self):
         """The bounded-lateness reorder buffer this (time) clause implies."""
         if not self.is_time:
@@ -346,6 +370,9 @@ class AggResult(NamedTuple):
     #: :mod:`repro.obs.counters` values — None when stats are off (the
     #: default), so the result pytree is unchanged for existing callers
     stats: Any = None
+    #: event-time streaming only: ``[rows]`` window end of each row (rows
+    #: are closed hopping windows; TS_MIN where a row holds none)
+    window_ends: Any = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -508,9 +535,14 @@ def plan(query: Query, *, backend: str | None = None, num_shards: int = 1,
             else "engine")
     if path == "stream" and query.window is not None \
             and query.window.is_time:
-        note = (note + "; " if note else "") + \
-            "event-time: panes close by watermark; evaluation at each " \
-            "push's watermark"
+        if _table_ops(query, num_shards, jnp.int32):
+            route = "event-time: pane-partial table, closed hopping windows"
+            if not _table_ops(query, num_shards, jnp.float32):
+                route += " (float keys with sum/mean: reorder buffer)"
+        else:
+            route = ("event-time: reorder buffer and time-pane store, "
+                     "closed hopping windows")
+        note = (note + "; " if note else "") + route
     elif path == "stream" and query.window is not None \
             and not query.window.per_group:
         # NOT the batch semantics: a streamed global window runs on the
@@ -520,6 +552,22 @@ def plan(query: Query, *, backend: str | None = None, num_shards: int = 1,
             "stream-window: ws serves as each group's per-group window"
     return Plan(query=query, backend=name, path=path, note=note,
                 num_shards=num_shards, stages=stages)
+
+
+def _table_ops(query: Query, num_shards: int, key_dtype) -> bool:
+    return (num_shards == 1 and all(_panestore.partial_path_names(
+        query.op_names, key_dtype)))
+
+
+def table_route(p: Plan, key_dtype=jnp.int32) -> bool:
+    """Whether the streaming event-time plan ``p`` runs, for keys of
+    ``key_dtype``, on the sorted pane-partial table
+    (:mod:`repro.core.panetable`): one device, and every op on the
+    partial path (float sum/mean keep the reorder buffer, whose replay
+    sums in a fixed order)."""
+    w = p.query.window
+    return (p.path == "stream" and w is not None and w.is_time
+            and _table_ops(p.query, p.num_shards, key_dtype))
 
 
 def _combiners(query: Query) -> tuple[Combiner | None, ...]:
@@ -573,6 +621,7 @@ def stream_fn(p: Plan, *, p_ports: int = 4, mesh=None,
         from repro.core import eventtime as _eventtime
         spec = q.window.store_spec()
         rspec = q.window.reorder_spec()
+        tspec = q.window.table_spec()
         time_range = q.window.range
         lateness = q.window.max_lateness
 
@@ -604,35 +653,50 @@ def stream_fn(p: Plan, *, p_ports: int = 4, mesh=None,
                                  "timestamps=")
             counters = None
             if collect_stats:
-                (rstate, pstate), counters = state
+                state, counters = state
+            if isinstance(state, _panetable.TableState) and counters is None:
+                out, state = _panetable.push(tspec, state, groups, keys,
+                                             timestamps, q.ops,
+                                             n_valid=n_valid)
+            elif isinstance(state, _panetable.TableState):
+                out, state, counters = _panetable.push(
+                    tspec, state, groups, keys, timestamps, q.ops,
+                    n_valid=n_valid, counters=counters)
             else:
-                rstate, pstate = state
+                rstate, pstate, next_end = state
+                if counters is None:
+                    emit, rstate = _eventtime.reorder_push(
+                        rspec, rstate, timestamps, groups, keys,
+                        n_valid=n_valid)
+                else:
+                    emit, rstate, counters = _eventtime.reorder_push(
+                        rspec, rstate, timestamps, groups, keys,
+                        n_valid=n_valid, counters=counters)
+                wm = rstate.max_ts - lateness
+                if counters is None:
+                    pstate = _panestore.push_time(
+                        spec, pstate, emit.groups, emit.keys, emit.ts,
+                        live=emit.live, retire_below=next_end - time_range)
+                else:
+                    pstate, counters = _panestore.push_time(
+                        spec, pstate, emit.groups, emit.keys, emit.ts,
+                        live=emit.live, retire_below=next_end - time_range,
+                        counters=counters)
+                out, next_end = _panestore.emit_time_windows(
+                    spec, pstate, q.ops, next_end, wm, tspec.rows,
+                    interpolate=q.interpolate)
+                out = _panetable.Emission(*out)
+                state = (rstate, pstate, next_end)
+                if counters is not None:
+                    counters = _c.put(counters, "late_dropped",
+                                      rstate.dropped)
+                    counters = _c.put(counters, "watermark", wm)
+                    counters = _c.bump(counters, "windows_emitted", jnp.sum(
+                        (out.num_groups > 0).astype(jnp.int32)))
+            ports = time_ports(out, p_ports)
             if counters is None:
-                emit, rstate = _eventtime.reorder_push(
-                    rspec, rstate, timestamps, groups, keys, n_valid=n_valid)
-            else:
-                emit, rstate, counters = _eventtime.reorder_push(
-                    rspec, rstate, timestamps, groups, keys, n_valid=n_valid,
-                    counters=counters)
-            wm = rstate.max_ts - lateness
-            if counters is None:
-                pstate = _panestore.push_time(
-                    spec, pstate, emit.groups, emit.keys, emit.ts,
-                    live=emit.live, retire_below=wm - time_range)
-            else:
-                pstate, counters = _panestore.push_time(
-                    spec, pstate, emit.groups, emit.keys, emit.ts,
-                    live=emit.live, retire_below=wm - time_range,
-                    counters=counters)
-                counters = _c.put(counters, "late_dropped", rstate.dropped)
-                counters = _c.put(counters, "watermark", wm)
-            g, values, valid, num = _panestore.replay(
-                spec, pstate, q.ops, interpolate=q.interpolate,
-                eval_time=wm)
-            rr = jnp.where(valid, jnp.arange(spec.capacity) % p_ports, -1)
-            if counters is None:
-                return (g, values, valid, num, rr), (rstate, pstate)
-            return (g, values, valid, num, rr), ((rstate, pstate), counters)
+                return ports, state
+            return ports, (state, counters)
 
         return time_step
 
@@ -709,20 +773,36 @@ def stream_fn(p: Plan, *, p_ports: int = 4, mesh=None,
     return step
 
 
-def _init_stream_counters(p: Plan) -> dict:
+def time_ports(out, p_ports: int = 4) -> tuple:
+    """An event-time :class:`repro.core.panetable.Emission` as the streaming
+    port tuple ``(groups, values, valid, num, rr, ends)``: ``[rows,
+    lanes]`` arrays, one row per emitted window."""
+    lanes = out.groups.shape[-1]
+    rr = jnp.where(out.valid, jnp.arange(lanes) % p_ports, -1)
+    return (out.groups, out.values, out.valid, out.num_groups, rr, out.ends)
+
+
+def _init_stream_counters(p: Plan, key_dtype=jnp.int32) -> dict:
     """The zeroed counters dict a stats-collecting stream carry starts
     from — keyed up front (every key the step will touch) so the carry
     pytree structure is stable from the first push on (one jit trace)."""
     from repro.core.eventtime import TS_MIN
     from repro.obs import counters as _c
     w = p.query.window
+    if table_route(p, key_dtype):
+        return _c.init(late_dropped=jnp.zeros((), jnp.int32),
+                       pane_evictions=jnp.zeros((), jnp.int32),
+                       watermark=jnp.asarray(TS_MIN, jnp.int32),
+                       windows_emitted=jnp.zeros((), jnp.int32),
+                       pane_rows_hwm=jnp.zeros((), jnp.int32))
     if w is not None and w.is_time:
         c = _c.init(reorder_depth_hwm=jnp.zeros((), jnp.int32),
                     reorder_forced_pops=jnp.zeros((), jnp.int32),
                     pane_evictions=jnp.zeros((), jnp.int32),
                     pane_occupancy_hwm=jnp.zeros((), jnp.int32),
                     late_dropped=jnp.zeros((), jnp.int32),
-                    watermark=jnp.asarray(TS_MIN, jnp.int32))
+                    watermark=jnp.asarray(TS_MIN, jnp.int32),
+                    windows_emitted=jnp.zeros((), jnp.int32))
         if p.num_shards > 1:
             c["watermark_lag"] = jnp.zeros((), jnp.int32)
         return c
@@ -756,7 +836,10 @@ def init_stream_state(p: Plan, key_dtype=jnp.int32,
     shape ``stream_fn(..., collect_stats=True)`` threads; pass the same
     flag to both (``execute`` does)."""
     from repro.core import segscan
-    if p.query.window is not None and p.query.window.is_time:
+    if table_route(p, key_dtype):
+        state = _panetable.init_table(p.query.window.table_spec(),
+                                      p.query.ops, key_dtype)
+    elif p.query.window is not None and p.query.window.is_time:
         from repro.core import eventtime as _eventtime
         rstate = _eventtime.init_reorder(p.query.window.reorder_spec(),
                                          key_dtype)
@@ -766,14 +849,15 @@ def init_stream_state(p: Plan, key_dtype=jnp.int32,
                 rstate)
         state = (rstate,
                  _panestore.init_store(p.query.window.store_spec(),
-                                       key_dtype))
+                                       key_dtype),
+                 jnp.asarray(_eventtime.TS_MIN, jnp.int32))
     elif p.query.window is not None:
         state = _panestore.init_store(p.query.window.store_spec(), key_dtype)
     else:
         state = tuple(segscan.init_carry(c, key_dtype)
                       for c in _combiners(p.query))
     if collect_stats:
-        return (state, _init_stream_counters(p))
+        return (state, _init_stream_counters(p, key_dtype))
     return state
 
 
@@ -1067,14 +1151,15 @@ def execute(plan_or_query, groups, keys=None, *, state=None, backend=None,
                 "start a new stream with the other setting")
         step = stream_fn(p, mesh=mesh, collect_stats=collect_stats)
         with _trace.span("dispatch", backend=p.backend, path="stream"):
+            ends = None
             if is_time:
-                (g, values, valid, num, _rr), new_state = step(
+                (g, values, valid, num, _rr, ends), new_state = step(
                     groups, keys, state, n_valid, timestamps)
             else:
                 (g, values, valid, num, _rr), new_state = step(
                     groups, keys, state, n_valid)
         stats = dict(new_state[1]) if collect_stats else None
-        res = AggResult(g, values, valid, num, stats)
+        res = AggResult(g, values, valid, num, stats, ends)
         if collect_stats:
             _observe_throughput(p, res, n, t0)
         return res, new_state

@@ -10,10 +10,10 @@ The contracts under test:
 * batch ``Window(range=R, slide=S)`` — windows cover ``[e - R, e)`` at
   slide multiples, on both strategies (per-window replay and the
   two-stack) and both backends (reference and Pallas interpret);
-* streaming — panes close by watermark advance; every per-push
-  evaluation matches a pure-Python window oracle at that watermark, and
-  the sharded path (per-shard buffers, min-merged watermark) agrees with
-  the same oracle at the merged watermark.
+* streaming — every push emits the closed hopping windows its watermark
+  has passed, each once, matching a pure-Python oracle of closed windows
+  (``_window_oracle``); the sharded path (per-shard buffers, min-merged
+  watermark) agrees with the same oracle at the merged watermark.
 """
 from __future__ import annotations
 
@@ -27,6 +27,8 @@ from repro.core import eventtime as et
 from repro.core.streaming import StreamingAggregator
 from repro.query import (Query, Window, execute, init_stream_state, plan,
                          stream_fn)
+
+from _window_oracle import ClosedWindows, got_rows
 
 OPS = ("min", "max", "sum", "count")
 
@@ -44,13 +46,6 @@ def _window_oracle(g, k, t, wm, rng_, ops):
             buckets.setdefault(int(gi), []).append(int(ki))
     return {gi: tuple(_py_op(op, vals) for op in ops)
             for gi, vals in sorted(buckets.items())}
-
-
-def _eval_dict(ports, ops):
-    gr, values, valid, _num, _rr = ports
-    va, gr = np.asarray(valid), np.asarray(gr)
-    return {int(gr[j]): tuple(int(np.asarray(values[op])[j]) for op in ops)
-            for j in range(gr.shape[0]) if va[j]}
 
 
 def _perturb(rng, ts, lateness):
@@ -283,9 +278,12 @@ def test_time_window_layout_needs_concrete_timestamps(rng):
 
 
 def _stream_setup(num_shards=1, reorder_capacity=64):
+    # capacity 64: room for every pane from the oldest window not yet
+    # emitted (a push that closes more than ceil(48/16) windows defers the
+    # rest, and their panes stay) — nothing is evicted
     q = Query(ops=OPS, streaming=True,
               window=Window(range=48, slide=16, max_lateness=24,
-                            reorder_capacity=reorder_capacity))
+                            reorder_capacity=reorder_capacity, capacity=64))
     p = plan(q, backend="reference",
              **({"num_shards": num_shards} if num_shards > 1 else {}))
     return q, p, stream_fn(p), init_stream_state(p, jnp.int32)
@@ -298,23 +296,37 @@ def _sorted_time_stream(rng, n, t_max=400, n_groups=4):
     return g, k, t
 
 
+def _dropped(state):
+    """Late tuples dropped so far, on either single-device route."""
+    return int(state.dropped if hasattr(state, "dropped")
+               else state[0].dropped)
+
+
 def test_streaming_evals_match_watermark_oracle(rng):
+    """Every push emits exactly the closed hopping windows its watermark
+    has passed (oldest first, at most ceil(R / S) a push), each matching
+    the window oracle."""
     N, B, L = 128, 32, 24
     g, k, t = _sorted_time_stream(rng, N)
     q, p, step, state = _stream_setup()
-    assert "watermark" in p.note
+    assert "closed hopping windows" in p.note
+    rows = q.window.table_spec().rows
+    oracle = ClosedWindows(48, 16, rows, OPS)
+    emitted = 0
     for i in range(0, N, B):
         ports, state = step(jnp.array(g[i:i + B]), jnp.array(k[i:i + B]),
                             state, None, jnp.array(t[i:i + B]))
         wm = int(np.max(t[:i + B])) - L
-        assert _eval_dict(ports, OPS) == _window_oracle(
-            g[:i + B], k[:i + B], t[:i + B], wm, 48, OPS)
+        want = oracle.push(g[i:i + B], k[i:i + B], t[i:i + B], wm)
+        assert got_rows(ports, OPS) == want
+        emitted += len(want)
+    assert emitted > 0
 
 
 def test_streaming_shuffled_ingest_bit_identical(rng):
-    """Per-push evaluations are bit-identical between in-order ingest and
+    """Per-push emissions are bit-identical between in-order ingest and
     any within-batch, within-lateness shuffle (same prefix, same
-    watermark, same released set)."""
+    watermark, same closed windows)."""
     N, B, L = 128, 32, 24
     g, k, t = _sorted_time_stream(rng, N)
 
@@ -325,8 +337,8 @@ def test_streaming_shuffled_ingest_bit_identical(rng):
             ports, state = step(jnp.array(gv[i:i + B]),
                                 jnp.array(kv[i:i + B]), state, None,
                                 jnp.array(tv[i:i + B]))
-            out.append(_eval_dict(ports, OPS))
-        assert int(state[0].dropped) == 0
+            out.append(got_rows(ports, OPS))
+        assert _dropped(state) == 0
         return out
 
     base = run(g, k, t)
@@ -337,12 +349,14 @@ def test_streaming_shuffled_ingest_bit_identical(rng):
         kw[i:i + B] = k[i:i + B][pp]
         tw[i:i + B] = t[i:i + B][pp]
     assert run(gw, kw, tw) == base
+    assert any(base)
 
 
 def test_streaming_global_shuffle_matches_at_watermarks(rng):
     """An arbitrary within-lateness shuffle moves tuples across push
-    boundaries, so watermarks differ per push — but evaluations at equal
-    watermarks are bit-identical, and the final one always matches."""
+    boundaries, so watermarks differ per push — but a window is emitted
+    only once closed, so both runs emit the same windows in the same
+    order, with bit-identical contents."""
     N, B, L = 128, 32, 24
     g, k, t = _sorted_time_stream(rng, N)
     pert = _perturb(rng, t, L)
@@ -355,58 +369,70 @@ def test_streaming_global_shuffle_matches_at_watermarks(rng):
             ports, state = step(jnp.array(gv[i:i + B]),
                                 jnp.array(kv[i:i + B]), state, None,
                                 jnp.array(tv[i:i + B]))
-            out.append((int(np.max(tv[:i + B])) - L,
-                        _eval_dict(ports, OPS)))
+            out += got_rows(ports, OPS)
+        assert _dropped(state) == 0
         return out
 
     base, shuf = run(g, k, t), run(gs_, ks_, ts_)
-    for wm_o, ev_o in base:
-        for wm_s, ev_s in shuf:
-            if wm_o == wm_s:
-                assert ev_o == ev_s
-    assert base[-1] == shuf[-1]
+    m = min(len(base), len(shuf))
+    assert m > 0 and base[:m] == shuf[:m]
 
 
 def test_sharded_streaming_min_watermark_oracle(rng):
     """num_shards=2: per-shard reorder buffers, releases gated on the
-    min-merged watermark; every evaluation matches the window oracle at
-    that merged watermark."""
+    min-merged watermark; every push emits the closed windows that merged
+    watermark has passed, each matching the window oracle."""
     N, B, L = 96, 32, 24
     g, k, t = _sorted_time_stream(rng, N)
     pert = _perturb(rng, t, L)
     g, k, t = g[pert], k[pert], t[pert]
-    _, p, step, state = _stream_setup(num_shards=2)
-    assert p.num_shards == 2
+    q, p, step, state = _stream_setup(num_shards=2)
+    assert p.num_shards == 2 and "reorder buffer" in p.note
+    oracle = ClosedWindows(48, 16, q.window.table_spec().rows, OPS)
     wm_shard = np.full(2, et.TS_MIN, np.int64)
+    emitted = 0
     for i in range(0, N, B):
         ports, state = step(jnp.array(g[i:i + B]), jnp.array(k[i:i + B]),
                             state, None, jnp.array(t[i:i + B]))
         halves = t[i:i + B].reshape(2, B // 2)
         wm_shard = np.maximum(wm_shard, halves.max(axis=1))
         gwm = int(wm_shard.min()) - L
-        assert _eval_dict(ports, OPS) == _window_oracle(
-            g[:i + B], k[:i + B], t[:i + B], gwm, 48, OPS)
+        want = oracle.push(g[i:i + B], k[i:i + B], t[i:i + B], gwm)
+        assert got_rows(ports, OPS) == want
+        emitted += len(want)
+    assert emitted > 0
 
 
 @pytest.mark.parametrize("num_shards", [None, 2])
 def test_streaming_aggregator_flush(rng, num_shards):
+    """Pushes then ``flush``: every window holding a tuple is emitted
+    exactly once, by a push once closed or by the flush."""
     N, B, L = 96, 32, 24
     g, k, t = _sorted_time_stream(rng, N)
     pert = _perturb(rng, t, L)
     g, k, t = g[pert], k[pert], t[pert]
-    agg = StreamingAggregator(
-        "min", window=Window(range=48, slide=16, max_lateness=L,
-                             reorder_capacity=64), num_shards=num_shards)
+    w = Window(range=48, slide=16, max_lateness=L, reorder_capacity=64,
+               capacity=64)
+    agg = StreamingAggregator("min", window=w, num_shards=num_shards)
+    oracle = ClosedWindows(48, 16, w.table_spec().rows, ("min",))
+    wm_shard = np.full(2, et.TS_MIN, np.int64)
+    seen = []
     for i in range(0, N, B):
-        agg.push(g[i:i + B], k[i:i + B], timestamps=t[i:i + B])
+        res = agg.push(g[i:i + B], k[i:i + B], timestamps=t[i:i + B])
+        seen += [e for e, _ in got_rows(res, ("min",))]
+        if num_shards:
+            wm_shard = np.maximum(wm_shard,
+                                  t[i:i + B].reshape(2, -1).max(axis=1))
+            wm = int(wm_shard.min()) - L
+        else:
+            wm = int(np.max(t[:i + B])) - L
+        assert got_rows(res, ("min",)) == oracle.push(
+            g[i:i + B], k[i:i + B], t[i:i + B], wm)
     fin = agg.flush()
-    end = int(np.max(t)) + 1  # flush evaluates past the last tuple
-    want = {gi: v[0]
-            for gi, v in _window_oracle(g, k, t, end, 48, ("min",)).items()}
-    va = np.asarray(fin.valid)
-    got = {int(np.asarray(fin.groups)[j]): int(np.asarray(fin.values)[j])
-           for j in range(va.shape[0]) if va[j]}
-    assert got == want
+    want = oracle.flush()
+    assert want and got_rows(fin, ("min",)) == want
+    seen += [e for e, _ in want]
+    assert seen == sorted(set(seen))
 
 
 @pytest.mark.parametrize("num_shards", [None, 2])

@@ -316,6 +316,48 @@ def test_reorder_buffer_carries_its_stage():
     assert set(_bench_stages().stage_names(text).values()) == {"reorder"}
 
 
+TABLE_QUERY = Query(ops=("count", "max"), streaming=True,
+                    window=Window(range=32, slide=16, max_lateness=8,
+                                  capacity=64))
+
+
+def test_table_route_carries_its_stages():
+    """The pane-partial table's push lowers to exactly its three stages."""
+    p = plan(TABLE_QUERY)
+    state = init_stream_state(p)
+    x = jnp.arange(32, dtype=jnp.int32)
+    step = stream_fn(p)
+    text = jax.jit(lambda st, t: step(t, t, st, None, t)) \
+        .lower(state, x).compile().as_text()
+    got = set(_bench_stages().stage_names(text).values())
+    assert got == {"time_partials", "table_merge", "window_emit"}
+    assert got <= set(obs_trace.STAGES)
+    assert all(obs_trace.STAGES[s][1] for s in got)
+
+
+def test_table_route_counters():
+    """late_dropped, watermark, windows_emitted, pane_rows_hwm and
+    pane_evictions ride the carry of a stats-collecting table stream; with
+    stats off the step traces no counter op."""
+    p = plan(TABLE_QUERY)
+    t = jnp.array([0, 5, 17, 40, 20, 41, 60, 70], jnp.int32)  # 20 is late
+    g = jnp.array([0, 1, 0, 1, 2, 0, 1, 0], jnp.int32)
+    on = init_stream_state(p, collect_stats=True)
+    ports, on = stream_fn(p, collect_stats=True)(g, g, on, None, t)
+    c = on[1]
+    assert int(c["late_dropped"]) == 1
+    assert int(c["watermark"]) == 70 - 8
+    # windows [-16, 16), [0, 32) and [16, 48) are closed at 62; two rows
+    assert int(c["windows_emitted"]) == 2 == int(jnp.sum(ports[3] > 0))
+    assert int(c["pane_rows_hwm"]) == 7 and int(c["pane_evictions"]) == 0
+    off = init_stream_state(p)
+    jx_off = jax.make_jaxpr(lambda s: stream_fn(p)(g, g, s, None, t))(off)
+    jx_on = jax.make_jaxpr(
+        lambda s: stream_fn(p, collect_stats=True)(g, g, s, None, t))(
+            init_stream_state(p, collect_stats=True))
+    assert _num_eqns(jx_off.jaxpr) < _num_eqns(jx_on.jaxpr)
+
+
 def test_stage_rejects_unknown_name():
     with pytest.raises(ValueError, match="unknown stage"):
         obs_trace.stage("dir-scan")

@@ -196,3 +196,31 @@ def test_kernels_lie_in_their_stages(one_chip, path):
     glue = {"dir_scan"}
     assert kernels and {names.get(k) for k in kernels} == want - glue
     assert want <= set(names.values())
+
+
+def test_pane_partial_table_step_compiles(one_chip):
+    """The NEXmark Q5 cell's streaming step at its sizes: 2^16 bids a push
+    into a pane-partial table of 2^21 rows, windows of 10 s every 5 s.  An
+    XLA program (streaming is a reference-backend feature), with its three
+    stages in the compiled text, and its carry small beside 16 GB."""
+    from repro.query import (Query, Window, init_stream_state, plan,
+                             stream_fn)
+
+    q = Query(ops=("count",), streaming=True,
+              window=Window(range=10000, slide=5000, max_lateness=3000,
+                            capacity=2 ** 21))
+    p = plan(q)
+    assert "pane-partial table" in p.note
+    step = stream_fn(p)
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_stream_state(p)))
+    col = jax.ShapeDtypeStruct((2 ** 16,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lambda g, k, t, st: step(g, k, st, None, t),
+                       donate_argnums=(3,)).lower(col, col, col, state) \
+        .compile()
+    text = compiled.as_text()
+    for stage in ("time_partials", "table_merge", "window_emit"):
+        assert f"repro.{stage}" in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 64 * 2 ** 20
