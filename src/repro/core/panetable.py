@@ -17,13 +17,17 @@ panes, and a group's rows are adjacent.  Each push runs three stages
   counted in ``dropped`` and never aggregated.  The push's live tuples are
   sorted by ``(group, pane)`` and each run is reduced (a segment
   reduction of the ops' lifted states): the push's partial rows.
-* ``table_merge`` — the partial rows go into the table by rank: a binary
-  search of the sorted table places each row, rows whose key is already
-  there are combined in place, and one scatter writes the kept table rows
-  and the new rows at their merged positions.  The rows of retired panes
-  (every window holding them already emitted) drop out of the same
-  scatter.  Rows beyond ``capacity`` are dropped and counted
-  (``pane_evictions``): the answers are then no longer exact.
+* ``table_merge`` — the partial rows go into the table with no gather or
+  scatter.  The table's pad tail takes them, reversed, so its lanes form
+  one bitonic sequence; log2(lanes) sweeps of a merge network sort it, with
+  the ops' states as payload; a key both sides hold lands on two adjacent
+  lanes and folds into the first; that lane's twin and the rows of retired
+  panes (every window holding them already emitted) drop out by a
+  butterfly compaction.  The short-distance sweeps and rounds run in VMEM
+  (:mod:`repro.kernels.tablemerge`).  A push that does not fit the pad tail
+  takes a network twice as wide (``table_merge_wide``); rows beyond
+  ``capacity`` are dropped and counted (``pane_evictions``): the answers
+  are then no longer exact.
 * ``window_emit`` — closed hopping windows: window ``[e - range, e)`` is
   emitted once, by the first push whose watermark ``max_ts -
   max_lateness`` is at or past ``e``, and only if it holds a tuple.  A push
@@ -34,12 +38,13 @@ panes, and a group's rows are adjacent.  Each push runs three stages
   ``capacity``-lane row.
   A push that emits no window skips the stage (``lax.cond``).
 
-Work per push is O(rows of the table + N log N): no ``[C, C]`` mask and no
-per-tuple step.  Timestamps are int32.
+Work per push is O(C log C + N log N) for a table of ``C`` lanes: no
+``[C, C]`` mask and no per-tuple step.  Timestamps are int32.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import NamedTuple
 
@@ -49,6 +54,9 @@ import jax.numpy as jnp
 from repro.core import engine as _engine
 from repro.core.combiners import Combiner, get_combiner
 from repro.core.eventtime import TS_MIN
+from repro.core.sorter import next_pow2
+from repro.kernels import common as _kernels
+from repro.kernels import tablemerge
 from repro.obs.trace import stage
 
 Array = jax.Array
@@ -235,80 +243,175 @@ def _push_partials(spec: TableSpec, state: TableState, groups, keys, ts,
             run_max[-1])
 
 
-def _search(group: Array, pane: Array, qg: Array, qp: Array) -> Array:
-    """For each query key, the number of table rows whose (group, pane) is
-    smaller: a binary search of the sorted table (pad rows sort last)."""
-    c = group.shape[0]
-
-    def step(_, bounds):
-        lo, hi = bounds
-        mid = (lo + hi) // 2
-        at = jnp.minimum(mid, c - 1)
-        less = (lo < hi) & _lex_less(group[at], pane[at], qg, qp)
-        return (jnp.where(less, mid + 1, lo),
-                jnp.where(less | (lo >= hi), hi, mid))
-
-    lo, _ = jax.lax.fori_loop(
-        0, c.bit_length(), step,
-        (jnp.zeros(qg.shape, jnp.int32), jnp.full(qg.shape, c, jnp.int32)))
-    return lo
+#: lanes of a row in the 2-D view the merge network works on
+_LANES = 128
 
 
-def _merge(spec: TableSpec, state: TableState, pg, pp, pstates, combiners):
+def _rows_shift(x, k: int, fill):
+    """``x`` ([rows, lanes]) moved ``k`` rows towards the end (``k < 0``:
+    the start), ``fill`` in the rows left empty."""
+    if k == 0:
+        return x
+    if abs(k) >= x.shape[0]:
+        return jnp.full_like(x, fill)
+    pad = jnp.full((abs(k), x.shape[1]), jnp.asarray(fill, x.dtype))
+    if k > 0:
+        return jnp.concatenate([pad, x[:-k]])
+    return jnp.concatenate([x[-k:], pad])
+
+
+def _flat_shift(x, d: int, fill):
+    """``x`` ([rows, lanes]) moved ``d`` lanes towards the end of its
+    row-major order (``d < 0``: the start), ``fill`` in the lanes left
+    empty: a row move for the whole rows, lane rotations for the rest."""
+    w = x.shape[1]
+    q, s = divmod(abs(d), w)
+    sign = 1 if d > 0 else -1
+    a = _rows_shift(x, sign * q, fill)
+    if s == 0:
+        return a
+    b = _rows_shift(x, sign * (q + 1), fill)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    if d > 0:
+        return jnp.where(lane >= s, jnp.roll(a, s, 1), jnp.roll(b, s, 1))
+    return jnp.where(lane < w - s, jnp.roll(a, -s, 1), jnp.roll(b, -s, 1))
+
+
+def _clean_sweep(cols, d: int):
+    """One ascending sweep of a bitonic merge over ``cols`` (a pytree of
+    [rows, lanes] leaves, keyed by its first two) at a distance of ``d``
+    rows: each lane of row ``i`` and the same lane of row ``i ^ d`` swap
+    where they are out of order."""
+    g, p = cols[:2]
+    lower = (jax.lax.broadcasted_iota(jnp.int32, g.shape, 0) & d) == 0
+    par = jax.tree.map(lambda x: jnp.where(lower, jnp.roll(x, -d, 0),
+                                           jnp.roll(x, d, 0)), cols)
+    swap = jnp.where(lower, _lex_less(par[0], par[1], g, p),
+                     _lex_less(g, p, par[0], par[1]))
+    return jax.tree.map(lambda x, y: jnp.where(swap, y, x), cols, par)
+
+
+def _butterfly_compact(live, cols, fills, tile: int):
+    """The ``live`` lanes of ``cols`` (a pytree of [rows, lanes] leaves)
+    moved to the front, in order; the lanes past them take ``fills``.  A
+    live lane's displacement (its lane less its rank) never falls along the
+    lanes, so moving each by one bit of it a round, lowest bit first, never
+    lands two on one lane (``kernels/common.butterfly_compact``).  The
+    rounds under ``tile`` rows run in VMEM, the others as row moves, and
+    only when some lane has that far to go (fewer dead lanes than a tile
+    holds, as after most pushes, leave them nothing to do)."""
+    v = live.astype(jnp.int32).reshape(-1)
+    rank = (jnp.cumsum(v) - v).reshape(live.shape)
+    disp = jnp.where(live, jnp.arange(v.size, dtype=jnp.int32).reshape(
+        live.shape) - rank, -1)                      # -1: no row here
+    disp, cols = tablemerge.compact_tiles(
+        disp, cols, tile, interpret=_kernels.default_interpret())
+    span = tile * live.shape[1]
+
+    def row_moves(args):
+        disp, cols = args
+        b = span
+        while b < v.size:
+            came = _flat_shift(disp, -b, -1)
+            arrive = (came > 0) & ((came & b) != 0)
+            stay = (disp >= 0) & ((disp & b) == 0)
+            cols = jax.tree.map(
+                lambda x, f: jnp.where(arrive, _flat_shift(x, -b, f), x),
+                cols, fills)
+            disp = jnp.where(arrive, came, jnp.where(stay, disp, -1))
+            b *= 2
+        return disp, cols
+
+    if span < v.size:
+        disp, cols = jax.lax.cond(jnp.max(disp) >= span, row_moves,
+                                  lambda args: args, (disp, cols))
+    return jax.tree.map(lambda x, f: jnp.where(disp >= 0, x, f), cols, fills)
+
+
+def _merge_lanes(table, push, fills, combiners, keep_from, width: int):
+    """Merge, fold and compact over ``width`` lanes (a power of two): the
+    table's columns, pad rows, then the push's columns reversed, so the
+    lanes form one bitonic sequence by (group, pane) for ``width``'s log2
+    ascending sweeps: those across tiles of ``tablemerge.tile_rows`` rows
+    as row rotations, the rest in VMEM (``tablemerge.merge_tiles``).  The
+    table's lanes past ``width - n`` must be pads.  ``table``, ``push`` and
+    ``fills`` are ``(group, pane, states)``.  Returns the table after the
+    push (``capacity`` lanes) and its live rows, which may exceed
+    ``capacity``."""
+    c, n = table[0].shape[0], push[0].shape[0]
+    t = min(c, width - n)
+    view = (width // min(width, _LANES), min(width, _LANES))
+    cols = jax.tree.map(
+        lambda x, y, f: jnp.concatenate([
+            x[:t], jnp.full((width - n - t,), f, x.dtype), y[::-1]
+        ]).reshape(view), table, push, fills)
+    tile = tablemerge.tile_rows(view[0], len(jax.tree.leaves(cols)) + 1)
+    d = view[0] // 2
+    while d >= tile:
+        cols = _clean_sweep(cols, d)
+        d //= 2
+    cols = tablemerge.merge_tiles(cols, tile,
+                                  interpret=_kernels.default_interpret())
+
+    # each side's keys are unique, so a key both hold sits on two adjacent
+    # lanes, in either order: the first takes the second's partial and the
+    # second dies, as do the rows of retired panes (table rows only: a
+    # push's row in a retired pane would be late or in no window)
+    g, p, states = cols
+    nxt = jax.tree.map(lambda x, f: _flat_shift(x, -1, f), cols, fills)
+    real = p != PAD_PANE
+    pair = real & (nxt[0] == g) & (nxt[1] == p)
+    second = _flat_shift(pair, 1, False)
+    for comb in combiners:
+        own = states[comb.name]
+        merged = comb.partial_merge(own, nxt[2][comb.name])
+        states[comb.name] = jax.tree.map(lambda m, x: jnp.where(pair, m, x),
+                                         merged, own)
+    live = real & ~second & (p >= keep_from)
+    cols = _butterfly_compact(live, (g, p, states), fills, tile)
+    return (jax.tree.map(lambda x: x.reshape(-1)[:c], cols),
+            jnp.sum(live.astype(jnp.int32)))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 5))
+def _merge(spec: TableSpec, state: TableState, pg, pp, pstates,
+           ops: tuple):
     """The ``table_merge`` stage: the push's partial rows (sorted, pad
-    tail) into the table by rank, dropping the rows of retired panes.
-    Returns ``(state, rows, evicted)``."""
-    c = spec.capacity
-    n = pg.shape[0]
-    real = pp != PAD_PANE
-    pos = _search(state.group, state.pane, pg, pp)
-    at = jnp.minimum(pos, c - 1)
-    match = real & (state.group[at] == pg) & (state.pane[at] == pp)
-    new = real & ~match
+    tail) into the table, dropping the rows of retired panes, with no
+    gather or scatter.  A bitonic network merges the two sorted sides, an
+    adjacent fold combines the keys both hold (every op here is
+    commutative), a butterfly compacts the dead lanes away.  The network
+    spans the table's lanes when the push fits its pad tail, else twice as
+    many, which keeps the ``capacity`` smallest keys (the rest are counted
+    as evicted).  ``ops`` are op names (static: one compile a shape, also
+    when called outside ``jit``).  Returns ``(state, rows, evicted,
+    wide)``."""
+    combiners = combiners_of(ops)
+    c, n = spec.capacity, pg.shape[0]
+    width = next_pow2(c)
+    fills = (PAD_GROUP, PAD_PANE,
+             {comb.name: comb.identity((), _key_dtype(state, comb))
+              for comb in combiners})
+    table = (state.group, state.pane, state.states)
+    push = (pg, pp, pstates)
+    keep_from = spec.keep_from(state.next_end)
 
-    # rows already in the table: fold this push's partial in place
-    hit = jnp.where(match, pos, c)
-    states = {}
-    for comb in combiners:
-        old = jax.tree.map(lambda x: x[at], state.states[comb.name])
-        merged = comb.partial_merge(old, pstates[comb.name])
-        states[comb.name] = jax.tree.map(
-            lambda x, m: x.at[hit].set(m, mode="drop", unique_indices=True),
-            state.states[comb.name], merged)
+    def over(lanes):
+        return lambda: _merge_lanes(table, push, fills, combiners, keep_from,
+                                    lanes)
 
-    # a table row's place: the rows kept before it, plus the new rows
-    # that sort before it; a new row's: the rows kept before its place in
-    # the table, plus the new rows before it.  One scatter writes both.
-    keep = (state.pane != PAD_PANE) & (state.pane
-                                       >= spec.keep_from(state.next_end))
-    keep_i = keep.astype(jnp.int32)
-    kept_before = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                   jnp.cumsum(keep_i)])       # [C + 1]
-    new_i = new.astype(jnp.int32)
-    new_before = jnp.cumsum(
-        jnp.zeros((c + 1,), jnp.int32).at[pos].add(new_i))[:c]
-    lane = jnp.arange(c, dtype=jnp.int32)
-    dest_t = jnp.where(keep, kept_before[:c] + new_before, c + lane)
-    rank = jnp.cumsum(new_i) - new_i
-    dest_n = jnp.where(new, kept_before[pos] + rank, 2 * c + jnp.arange(n))
-    dest = jnp.concatenate([dest_t, dest_n])
-
-    def place(x, y, fill):
-        buf = jnp.full((c,), jnp.asarray(fill), x.dtype)
-        return buf.at[dest].set(jnp.concatenate([x, y]), mode="drop",
-                                unique_indices=True)
-
-    out = {}
-    for comb in combiners:
-        out[comb.name] = jax.tree.map(
-            place, states[comb.name], pstates[comb.name],
-            comb.identity((), _key_dtype(state, comb)))
-    rows = kept_before[c] + jnp.sum(new_i)
-    new_state = state._replace(
-        group=place(state.group, pg, PAD_GROUP),
-        pane=place(state.pane, pp, PAD_PANE),
-        states=out)
-    return new_state, jnp.minimum(rows, c), jnp.maximum(rows - c, 0)
+    if n >= width:
+        wide = jnp.asarray(True)
+        cols, live = over(next_pow2(width + n))()
+    else:
+        rows = jnp.sum((state.pane != PAD_PANE).astype(jnp.int32))
+        wide = rows + n > width
+        cols, live = jax.lax.cond(wide, over(next_pow2(width + n)),
+                                  over(width))
+    g, p, states = cols
+    return (state._replace(group=g, pane=p, states=states),
+            jnp.minimum(live, c), jnp.maximum(live - c, 0),
+            wide.astype(jnp.int32))
 
 
 def _window_ends(spec: TableSpec, state: TableState, wm):
@@ -416,7 +519,8 @@ def push(spec: TableSpec, state: TableState, groups: Array, keys: Array,
     """One push: partials, merge, emission (see the module doc).  Returns
     ``(Emission, state)``, or ``(Emission, state, counters)`` with an
     :mod:`repro.obs.counters` dict (``late_dropped``, ``watermark``,
-    ``pane_evictions``, ``pane_rows_hwm``, ``windows_emitted``)."""
+    ``pane_evictions``, ``pane_rows_hwm``, ``table_merge_wide``,
+    ``windows_emitted``)."""
     combiners = combiners_of(ops)
     groups = jnp.asarray(groups, jnp.int32)
     ts = jnp.asarray(ts, jnp.int32)
@@ -428,8 +532,8 @@ def push(spec: TableSpec, state: TableState, groups: Array, keys: Array,
         pg, pp, pstates, late, max_ts = _push_partials(
             spec, state, groups, keys, ts, live, combiners)
     with stage("table_merge"):
-        state, rows, evicted = _merge(spec, state, pg, pp, pstates,
-                                      combiners)
+        state, rows, evicted, wide = _merge(
+            spec, state, pg, pp, pstates, tuple(c.name for c in combiners))
     state = state._replace(max_ts=max_ts, dropped=state.dropped + late)
     wm = max_ts - spec.max_lateness
     out, state = emit(spec, state, ops, wm)
@@ -440,6 +544,7 @@ def push(spec: TableSpec, state: TableState, groups: Array, keys: Array,
     counters = _c.put(counters, "watermark", wm)
     counters = _c.bump(counters, "pane_evictions", evicted)
     counters = _c.high_water(counters, "pane_rows_hwm", rows)
+    counters = _c.bump(counters, "table_merge_wide", wide)
     counters = _c.bump(counters, "windows_emitted",
                        jnp.sum((out.num_groups > 0).astype(jnp.int32)))
     return out, state, counters

@@ -7,6 +7,10 @@
                 pane variant: sort WA-panes once, bitonic-merge P = WS/WA
                 presorted panes per window in VMEM (sort work amortised
                 across the P windows sharing each pane)
+  * tablemerge — the pane-partial table's merge: the short-distance
+                bitonic sweeps and butterfly-compaction rounds, a tile
+                of the table per grid step (one module, called from
+                ``repro.core.panetable``)
 
 Each subpackage ships ``kernel.py`` (pl.pallas_call + BlockSpec),
 ``ops.py`` (jit'd wrapper, auto interpret-mode on CPU) and ``ref.py``

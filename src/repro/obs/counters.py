@@ -29,6 +29,8 @@ Counter names used by the engine:
 ``watermark``              current (min-merged) event-time watermark
 ``windows_emitted``        closed event-time windows emitted so far
 ``pane_rows_hwm``          high-water mark of rows in the pane-partial table
+``table_merge_wide``       pushes whose rows did not fit the pane-partial
+                           table's pad tail (its merge took twice the lanes)
 ``watermark_lag``          max shard watermark minus the merged global watermark
 ``stream_tuples``          tuples pushed through a streaming carry
 ``stream_emitted``         groups emitted (retired) by streaming pushes

@@ -794,7 +794,8 @@ def _init_stream_counters(p: Plan, key_dtype=jnp.int32) -> dict:
                        pane_evictions=jnp.zeros((), jnp.int32),
                        watermark=jnp.asarray(TS_MIN, jnp.int32),
                        windows_emitted=jnp.zeros((), jnp.int32),
-                       pane_rows_hwm=jnp.zeros((), jnp.int32))
+                       pane_rows_hwm=jnp.zeros((), jnp.int32),
+                       table_merge_wide=jnp.zeros((), jnp.int32))
     if w is not None and w.is_time:
         c = _c.init(reorder_depth_hwm=jnp.zeros((), jnp.int32),
                     reorder_forced_pops=jnp.zeros((), jnp.int32),

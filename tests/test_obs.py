@@ -336,9 +336,10 @@ def test_table_route_carries_its_stages():
 
 
 def test_table_route_counters():
-    """late_dropped, watermark, windows_emitted, pane_rows_hwm and
-    pane_evictions ride the carry of a stats-collecting table stream; with
-    stats off the step traces no counter op."""
+    """late_dropped, watermark, windows_emitted, pane_rows_hwm,
+    pane_evictions and table_merge_wide ride the carry of a
+    stats-collecting table stream; with stats off the step traces no
+    counter op."""
     p = plan(TABLE_QUERY)
     t = jnp.array([0, 5, 17, 40, 20, 41, 60, 70], jnp.int32)  # 20 is late
     g = jnp.array([0, 1, 0, 1, 2, 0, 1, 0], jnp.int32)
@@ -350,6 +351,7 @@ def test_table_route_counters():
     # windows [-16, 16), [0, 32) and [16, 48) are closed at 62; two rows
     assert int(c["windows_emitted"]) == 2 == int(jnp.sum(ports[3] > 0))
     assert int(c["pane_rows_hwm"]) == 7 and int(c["pane_evictions"]) == 0
+    assert int(c["table_merge_wide"]) == 0       # 8 rows fit 64 lanes
     off = init_stream_state(p)
     jx_off = jax.make_jaxpr(lambda s: stream_fn(p)(g, g, s, None, t))(off)
     jx_on = jax.make_jaxpr(

@@ -198,13 +198,18 @@ def test_kernels_lie_in_their_stages(one_chip, path):
     assert want <= set(names.values())
 
 
-def test_pane_partial_table_step_compiles(one_chip):
+def test_pane_partial_table_step_compiles(one_chip, monkeypatch):
     """The NEXmark Q5 cell's streaming step at its sizes: 2^16 bids a push
     into a pane-partial table of 2^21 rows, windows of 10 s every 5 s.  An
-    XLA program (streaming is a reference-backend feature), with its three
-    stages in the compiled text, and its carry small beside 16 GB."""
+    XLA program (streaming is a reference-backend feature) but for the
+    merge's two Pallas kernels, which the step picks as on a TPU; its three
+    stages in the compiled text, no gather or scatter in the merge, and its
+    carry small beside 16 GB."""
+    from repro.kernels import common
     from repro.query import (Query, Window, init_stream_state, plan,
                              stream_fn)
+
+    monkeypatch.setattr(common, "is_cpu", lambda devices=None: False)
 
     q = Query(ops=("count",), streaming=True,
               window=Window(range=10000, slide=5000, max_lateness=3000,
@@ -222,5 +227,9 @@ def test_pane_partial_table_step_compiles(one_chip):
     text = compiled.as_text()
     for stage in ("time_partials", "table_merge", "window_emit"):
         assert f"repro.{stage}" in text
+    merge = [line for line in text.splitlines() if "repro.table_merge" in line]
+    assert sum("tpu_custom_call" in line for line in merge) == 4
+    assert not any(" gather(" in line or " scatter(" in line
+                   for line in merge)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes < 64 * 2 ** 20
